@@ -95,7 +95,7 @@ def run_records() -> List[dict]:
     xla = jax.jit(lambda v, i, w: delta_codec.topk_scatter_reduce(
         v, i, w, m))
     mosaic = jax.jit(lambda v, i, w: delta_codec.topk_scatter_reduce_mosaic(
-        v, i, w, m, interpret=ops.INTERPRET))
+        v, i, w, m, interpret=ops.interpret_mode()))
     recs.append({"name": "kern_topk_scatter_reduce_xla",
                  "kernel_us": _time(xla, vals, idx, weights),
                  "oracle_us": us_dense,
@@ -110,7 +110,7 @@ def run_records() -> List[dict]:
     apply_want = refv.at[i1].add(v1)     # XLA scatter-add == dense apply
     xla_a = jax.jit(delta_codec.topk_scatter_apply)
     mosaic_a = jax.jit(lambda r, v, i: delta_codec.topk_scatter_apply_mosaic(
-        r, v, i, interpret=ops.INTERPRET))
+        r, v, i, interpret=ops.interpret_mode()))
     us_oracle = _time(lambda r, v, i: r.at[i].add(v), refv, v1, i1)
     recs.append({"name": "kern_topk_scatter_apply_xla",
                  "kernel_us": _time(xla_a, refv, v1, i1),

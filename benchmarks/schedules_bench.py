@@ -31,6 +31,7 @@ from repro.configs.base import FedConfig
 from repro.core import (FedAvgTrainer, RuntimeModel, make_eval_fn,
                         quantize_k, run_reference_rounds)
 from repro.data import make_paper_task
+from repro.launch.mesh import make_mesh
 from repro.models import small
 
 SCHEDULES = [
@@ -173,7 +174,7 @@ def run_backend_compare(rounds: int = 60, *, task_name: str = "sent140",
     loss_fn = lambda p, b: small.task_loss(p, task, b)
     params0 = small.init_task_model(jax.random.PRNGKey(seed), task)
     rt = RuntimeModel(task.model_size_mb, task.runtime, clients_per_round)
-    mesh = jax.make_mesh((len(jax.devices()), 1), ("data", "model"))
+    mesh = make_mesh((len(jax.devices()), 1), ("data", "model"))
     backends = [
         ("local", lambda: None),
         ("mesh_parallel", lambda: MeshBackend(mesh, strategy="parallel")),

@@ -19,9 +19,9 @@ def _mesh_factory(*, mesh=None, strategy: str = "parallel", groups: int = 1,
     the geometry ``launch/train.py --backend mesh`` always used. Pass a
     concrete ``mesh`` to control the topology."""
     import jax
+    from repro.launch.mesh import make_mesh
     if mesh is None:
-        n_dev = len(jax.devices())
-        mesh = jax.make_mesh((n_dev, 1), ("data", "model"))
+        mesh = make_mesh((len(jax.devices()), 1), ("data", "model"))
     return MeshBackend(mesh, strategy=strategy, groups=groups, reduce=reduce)
 
 

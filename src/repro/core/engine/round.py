@@ -558,6 +558,12 @@ class RoundEngine:
         zeros = be.place_params(jax.tree.map(
             lambda p: jnp.zeros(p.shape, jnp.float32), params))
         acc = (zeros, zeros if agg_ef else ())
+        # Each params-sized f32 buffer is dropped as soon as no later call
+        # reads it: the zeros, every slab's inputs (the previous
+        # accumulators among them) and, under aggregate EF, the slab's
+        # pass-through residual. At published widths each one held across
+        # the next slab or the finalize is 1.86 GB of a 16 GB chip.
+        del zeros
         eta = jnp.asarray(eta, jnp.float32)
         firsts, lasts, ef_parts = [], [], []
         for sb in slabs:
@@ -577,6 +583,7 @@ class RoundEngine:
             lasts.append(l)
             if per_client:
                 ef_parts.append(ef)
+            del args, ef
         if not firsts:
             raise ValueError("run_round_chunked got an empty slab stream")
         fargs = (params, acc, server_state)

@@ -21,16 +21,10 @@ PyTree = Any
 
 def executable_peak_bytes(exe) -> int:
     """Live bytes for one compiled executable: arguments + outputs + the
-    temp high-water mark, minus donated/aliased double counting. Returns 0
-    when the runtime doesn't expose memory stats (non-XLA backends)."""
-    try:
-        ma = exe.memory_analysis()
-    except Exception:                      # pragma: no cover - runtime-dep
-        return 0
-    return int(getattr(ma, "argument_size_in_bytes", 0)
-               + getattr(ma, "output_size_in_bytes", 0)
-               + getattr(ma, "temp_size_in_bytes", 0)
-               - getattr(ma, "alias_size_in_bytes", 0))
+    temp high-water mark, minus donated/aliased double counting."""
+    ma = exe.memory_analysis()
+    return int(ma.argument_size_in_bytes + ma.output_size_in_bytes
+               + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
 
 
 def executable_peak_mb(exe) -> float:

@@ -60,7 +60,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels.fedavg_reduce import (DEFAULT_BLOCK, _block_reduce,
@@ -129,19 +128,20 @@ def int8_decompress_reduce_sharded(q, w_eff, qr=None, wr_eff=None, *, mesh,
                                     interpret, out_dtype=jnp.float32)
             return psum_tiers(partial, axes, reduce_tiers)
 
-        # check_rep=False: no replication rule for pallas_call; the psum
+        # check_vma=False: pallas_call has no varying-axes rule; the psum
         # makes the P() out_spec replication explicit (as fedavg_reduce)
-        return shard_map(local, mesh=mesh,
-                         in_specs=(P(axes, None), P(axes)),
-                         out_specs=P(), check_rep=False)(q, w_eff)
+        return jax.shard_map(local, mesh=mesh,
+                             in_specs=(P(axes, None), P(axes)),
+                             out_specs=P(), check_vma=False)(q, w_eff)
 
     def local(x, xr, w, wr):
         partial = _block_reduce2(x, xr, w, wr, block, interpret)
         return psum_tiers(partial, axes, reduce_tiers)
 
-    return shard_map(local, mesh=mesh,
-                     in_specs=(P(axes, None), P(axes, None), P(axes), P(axes)),
-                     out_specs=P(), check_rep=False)(q, qr, w_eff, wr_eff)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(P(axes, None), P(axes, None), P(axes),
+                                   P(axes)),
+                         out_specs=P(), check_vma=False)(q, qr, w_eff, wr_eff)
 
 
 # ---------------------------------------------------------------------------
@@ -223,16 +223,17 @@ def int8_decode_apply_sharded(ref, q, s, qr=None, rs=None, *, mesh, axes,
         def local(r, x, sc):
             return _block_apply(r, x, sc, None, None, block, interpret)
 
-        return shard_map(local, mesh=mesh,
-                         in_specs=(P(axes), P(axes), P(None)),
-                         out_specs=P(axes), check_rep=False)(ref, q, s)
+        return jax.shard_map(local, mesh=mesh,
+                             in_specs=(P(axes), P(axes), P(None)),
+                             out_specs=P(axes), check_vma=False)(ref, q, s)
 
     def local(r, x, sc, xr, rsc):
         return _block_apply(r, x, sc, xr, rsc, block, interpret)
 
-    return shard_map(local, mesh=mesh,
-                     in_specs=(P(axes), P(axes), P(None), P(axes), P(None)),
-                     out_specs=P(axes), check_rep=False)(ref, q, s, qr, rs)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(P(axes), P(axes), P(None), P(axes),
+                                   P(None)),
+                         out_specs=P(axes), check_vma=False)(ref, q, s, qr, rs)
 
 
 def topk_scatter_apply(ref, vals, idx) -> jnp.ndarray:
@@ -384,10 +385,10 @@ def topk_scatter_reduce_sharded(vals, idx, weights, size: int, *, mesh,
         partial = topk_scatter_reduce_mosaic(
             v, ix, w, size, block_m=block_m, block_s=block_s,
             interpret=interpret)
-        # check_rep=False: no replication rule for pallas_call; the psum
+        # check_vma=False: pallas_call has no varying-axes rule; the psum
         # makes the P() out_spec replication explicit (as fedavg_reduce)
         return psum_tiers(partial, axes, reduce_tiers)
 
-    return shard_map(local, mesh=mesh,
-                     in_specs=(P(axes, None), P(axes, None), P(axes)),
-                     out_specs=P(), check_rep=False)(vals, idx, weights)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(P(axes, None), P(axes, None), P(axes)),
+                         out_specs=P(), check_vma=False)(vals, idx, weights)

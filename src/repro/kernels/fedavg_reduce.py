@@ -35,7 +35,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 DEFAULT_BLOCK = 4096
@@ -121,9 +120,9 @@ def fedavg_reduce_sharded(client_stack: jnp.ndarray, weights: jnp.ndarray, *,
                                 out_dtype=jnp.float32)
         return psum_tiers(partial, axes, reduce_tiers)
 
-    # check_rep=False: shard_map has no replication rule for pallas_call;
+    # check_vma=False: shard_map has no varying-axes rule for pallas_call;
     # the psum makes the out_spec P() replication explicit ourselves
-    out = shard_map(local, mesh=mesh,
-                    in_specs=(P(axes, None), P(axes)),
-                    out_specs=P(), check_rep=False)(client_stack, weights)
+    out = jax.shard_map(local, mesh=mesh,
+                        in_specs=(P(axes, None), P(axes)),
+                        out_specs=P(), check_vma=False)(client_stack, weights)
     return out.astype(client_stack.dtype)

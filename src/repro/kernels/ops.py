@@ -1,8 +1,10 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-On CPU (this container) every kernel runs with interpret=True — the kernel
-body executes as jax ops, which is how correctness is validated offline. On
-TPU the same pallas_call lowers to Mosaic. ``INTERPRET`` auto-detects.
+Off TPU every kernel runs with interpret=True — the kernel body executes as
+jax ops, which is how correctness is validated on the CPU. On TPU the same
+pallas_call lowers to Mosaic. ``interpret_mode()`` decides per call, never
+at import, so importing the package initialises no JAX backend (on a TPU
+host, initialising one takes the chip).
 
 Layout adapters live here: the model layers use (B, S, H, hd) attention
 tensors while the kernel wants (B, H, S, hd); SSD per-head arrangement and
@@ -22,9 +24,12 @@ from repro.kernels import flash_attention as _fa
 from repro.kernels import moe_gmm as _gmm
 from repro.kernels import ssd_scan as _ssd
 
-INTERPRET = jax.default_backend() != "tpu"
-
 PyTree = Any
+
+
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run interpreted: everywhere but on a TPU."""
+    return jax.default_backend() != "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -33,7 +38,7 @@ PyTree = Any
 
 def fedavg_reduce(client_stack: jnp.ndarray, weights: jnp.ndarray) -> jnp.ndarray:
     """(N, M) x (N,) -> (M,)."""
-    return _fr.fedavg_reduce(client_stack, weights, interpret=INTERPRET)
+    return _fr.fedavg_reduce(client_stack, weights, interpret=interpret_mode())
 
 
 def fedavg_reduce_tree(client_params: PyTree, weights: jnp.ndarray) -> PyTree:
@@ -57,7 +62,7 @@ def fedavg_reduce_sharded(client_stack: jnp.ndarray, weights: jnp.ndarray, *,
     (``reduce_tiers`` selects the hierarchical grouped reduce, §11)."""
     return _fr.fedavg_reduce_sharded(client_stack, weights, mesh=mesh,
                                      client_axes=client_axes,
-                                     interpret=INTERPRET,
+                                     interpret=interpret_mode(),
                                      reduce_tiers=reduce_tiers)
 
 
@@ -86,7 +91,7 @@ def int8_delta_reduce(q, w_eff, qr=None, wr_eff=None) -> jnp.ndarray:
     q (N, M) int8, w_eff (N,) = weights * per-client scales -> (M,) f32.
     Optional residual plane (two-level codec) fuses into the same pass."""
     return _dc.int8_decompress_reduce(q, w_eff, qr, wr_eff,
-                                      interpret=INTERPRET)
+                                      interpret=interpret_mode())
 
 
 def int8_delta_reduce_sharded(q, w_eff, qr=None, wr_eff=None, *, mesh,
@@ -97,7 +102,7 @@ def int8_delta_reduce_sharded(q, w_eff, qr=None, wr_eff=None, *, mesh,
     return _dc.int8_decompress_reduce_sharded(q, w_eff, qr, wr_eff,
                                               mesh=mesh,
                                               client_axes=client_axes,
-                                              interpret=INTERPRET,
+                                              interpret=interpret_mode(),
                                               reduce_tiers=reduce_tiers)
 
 
@@ -111,7 +116,7 @@ MOSAIC_SCATTER_MAX_INTERPRET_WORK = 1 << 20
 def mosaic_scatter_ok(payload_entries: int, size: int) -> bool:
     """Whether the one-hot Mosaic formulation is the right scatter for a
     ``payload_entries x size`` dense work volume on this backend."""
-    return ((not INTERPRET)
+    return ((not interpret_mode())
             or payload_entries * size <= MOSAIC_SCATTER_MAX_INTERPRET_WORK)
 
 
@@ -121,7 +126,7 @@ def topk_delta_reduce(vals, idx, weights, size: int) -> jnp.ndarray:
     large-payload interpret fallback/oracle."""
     if mosaic_scatter_ok(int(vals.shape[0]) * int(vals.shape[1]), size):
         return _dc.topk_scatter_reduce_mosaic(vals, idx, weights, size,
-                                              interpret=INTERPRET)
+                                              interpret=interpret_mode())
     return _dc.topk_scatter_reduce(vals, idx, weights, size)
 
 
@@ -133,14 +138,14 @@ def topk_delta_reduce_sharded(vals, idx, weights, size: int, *, mesh,
     return _dc.topk_scatter_reduce_sharded(vals, idx, weights, size,
                                            mesh=mesh,
                                            client_axes=client_axes,
-                                           interpret=INTERPRET,
+                                           interpret=interpret_mode(),
                                            reduce_tiers=reduce_tiers)
 
 
 def int8_delta_apply(ref, q, s, qr=None, rs=None) -> jnp.ndarray:
     """Downlink reconstruction: fused dequantise + add-to-ref
     (``ref + q*s [+ qr*rs]``), ref (M,) -> (M,) in ``ref.dtype``."""
-    return _dc.int8_decode_apply(ref, q, s, qr, rs, interpret=INTERPRET)
+    return _dc.int8_decode_apply(ref, q, s, qr, rs, interpret=interpret_mode())
 
 
 def int8_delta_apply_sharded(ref, q, s, qr=None, rs=None, *, mesh,
@@ -148,7 +153,7 @@ def int8_delta_apply_sharded(ref, q, s, qr=None, rs=None, *, mesh,
     """Mesh variant: flat vector sharded over ``axes``, per-shard fused
     decode-apply (elementwise — no collective; DESIGN.md §8.6)."""
     return _dc.int8_decode_apply_sharded(ref, q, s, qr, rs, mesh=mesh,
-                                         axes=axes, interpret=INTERPRET)
+                                         axes=axes, interpret=interpret_mode())
 
 
 def topk_delta_apply(ref, vals, idx) -> jnp.ndarray:
@@ -158,7 +163,7 @@ def topk_delta_apply(ref, vals, idx) -> jnp.ndarray:
     large-payload interpret fallback/oracle."""
     if mosaic_scatter_ok(int(vals.shape[0]), int(ref.size)):
         return _dc.topk_scatter_apply_mosaic(ref, vals, idx,
-                                             interpret=INTERPRET)
+                                             interpret=interpret_mode())
     return _dc.topk_scatter_apply(ref, vals, idx)
 
 
@@ -212,7 +217,7 @@ def _flash_fwd_impl(q, k, v, causal, window, softcap):
     # mask only when Sq == Sk; otherwise mask by shifting scores — we simply
     # require no key padding for non-causal use.
     out = _fa.flash_attention(qp, kp, vp, causal=causal, window=window,
-                              softcap=softcap, interpret=INTERPRET,
+                              softcap=softcap, interpret=interpret_mode(),
                               scale=1.0 / (hd ** 0.5))
     if pk and not causal:
         raise ValueError("non-causal flash path requires Sk % 128 == 0")
@@ -265,7 +270,7 @@ def ssd_scan(x, dt, a_log, b, c, d, *, chunk: int = 256):
     cr = jnp.broadcast_to(c[:, None], (B, H, Sp, N)).reshape(B * H, NC, chunk, N)
     y, fs = _ssd.ssd_scan(xr.astype(jnp.float32), dtr.astype(jnp.float32),
                           ar.astype(jnp.float32), br.astype(jnp.float32),
-                          cr.astype(jnp.float32), interpret=INTERPRET)
+                          cr.astype(jnp.float32), interpret=interpret_mode())
     y = jnp.moveaxis(y.reshape(B, H, Sp, P), 1, 2)[:, :S]
     y = y + x[:, :S] * d[None, None, :, None]
     return y.astype(x.dtype), fs.reshape(B, H, N, P)
@@ -279,7 +284,7 @@ def gmm(x, w):
     """(E, C, d) @ (E, d, f) -> (E, C, f), padding C to the 128 tile."""
     E, C, d = x.shape
     xp, pc = _pad_axis(x, 1, 128)
-    out = _gmm.gmm(xp, w, interpret=INTERPRET)
+    out = _gmm.gmm(xp, w, interpret=interpret_mode())
     return out[:, :C] if pc else out
 
 

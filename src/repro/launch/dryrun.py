@@ -1,12 +1,15 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (architecture x input shape) on
 the production meshes, and extract the roofline terms.
 
-The two lines above MUST run before any other import (jax locks the device
-count at first init); smoke tests and benches do NOT go through this module
-and keep seeing one CPU device.
+The lines above MUST run before any other import (jax locks the platform
+and device count at first init). This is a fake-device tool: it pins itself
+to 512 placeholder CPU devices, so neither it nor its ``--all`` children
+(which inherit the environment) ever take an accelerator. Smoke tests and
+benches do NOT go through this module and keep seeing one CPU device.
 
 Usage:
     PYTHONPATH=src python -m repro.launch.dryrun --arch qwen2-7b --shape train_4k
@@ -263,8 +266,6 @@ def run_case(arch_name: str, shape_name: str, multi_pod: bool,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):       # jax<=0.4.x returns [dict] per device
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     # Loop-aware accounting: XLA:CPU cost_analysis counts while bodies once
     # (verified K=1 == K=4), so FLOPs/bytes/collectives are re-derived from

@@ -26,15 +26,14 @@ counters per point.
     PYTHONPATH=src python -m repro.launch.train --spec run.json \\
         --sweep fed.k0=2,4,8 --share-k-grid
 
-Opt-in warm-start across *invocations*: ``--compile-cache DIR`` wires
-JAX's persistent compilation cache, so a repeated fleet skips XLA compiles
-entirely.
+Run as a script, it warm-starts across *invocations* from JAX's persistent
+compilation cache (``launch/compile_cache.py``), so a repeated fleet skips
+XLA compiles entirely.
 """
 from __future__ import annotations
 
 import argparse
 import csv
-import os
 import time
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
@@ -44,26 +43,11 @@ from repro.api import ExperimentSpec, build
 from repro.api.sweep import SweepPoint, expand_sweep, spec_program_key
 from repro.core.engine.round import ExecutableRegistry
 from repro.core.mem import trainer_peak_mb
+from repro.launch.compile_cache import enable_compile_cache
 
 CSV_FIELDS = ("label", "overrides", "final_loss", "min_loss", "rounds",
               "wall_s", "rounds_per_sec", "uplink_mbit", "downlink_mbit",
               "peak_mb", "compiles", "shared", "dispatches")
-
-
-def enable_persistent_cache(path: str) -> bool:
-    """Opt-in JAX persistent compilation cache: repeated fleet invocations
-    reload AOT executables from ``path`` instead of re-compiling. Returns
-    False (without raising) on runtimes that don't support it — the fleet
-    still runs, just cold."""
-    try:
-        import jax
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        return True
-    except Exception:
-        return False
 
 
 @dataclass(frozen=True)
@@ -275,19 +259,12 @@ def make_parser() -> argparse.ArgumentParser:
                          "sweep points share bucket executables")
     ap.add_argument("--csv", default=None, metavar="FILE.csv",
                     help="write the consolidated leaderboard CSV here")
-    ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="enable JAX's persistent compilation cache in DIR "
-                         "(warm-start repeated fleet invocations)")
     ap.add_argument("--quiet", action="store_true")
     return ap
 
 
 def main(argv=None) -> FleetResult:
     args = make_parser().parse_args(argv)
-    if args.compile_cache:
-        ok = enable_persistent_cache(args.compile_cache)
-        print(f"[fleet] persistent compile cache: "
-              f"{'on, ' + args.compile_cache if ok else 'unavailable'}")
     base = ExperimentSpec.load(args.spec) if args.spec else ExperimentSpec()
     if args.overrides:
         base = base.with_overrides(*args.overrides)
@@ -306,4 +283,5 @@ def main(argv=None) -> FleetResult:
 
 
 if __name__ == "__main__":
+    print(f"[fleet] persistent compile cache: {enable_compile_cache()}")
     main()

@@ -26,6 +26,7 @@ import numpy as np
 
 from repro.api import ExperimentSpec, build
 from repro.configs import ARCHS
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def make_parser() -> argparse.ArgumentParser:
@@ -52,7 +53,11 @@ def make_parser() -> argparse.ArgumentParser:
                          "packed")
     # --- legacy flags (translated to a spec) ------------------------
     ap.add_argument("--arch", choices=sorted(ARCHS), default="qwen1.5-0.5b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="cut the arch to the smoke-test shape "
+                         "(configs/base.py reduced()); --no-reduced runs "
+                         "its published widths and depth")
     ap.add_argument("--rounds", type=int, default=None,
                     help="round count (also applies on top of --spec)")
     ap.add_argument("--clients", type=int, default=24)
@@ -272,4 +277,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    print(f"[train] persistent compile cache: {enable_compile_cache()}")
     main()

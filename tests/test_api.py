@@ -6,7 +6,10 @@ directly-constructed FedAvgTrainer, across backends x transports x
 samplers (DESIGN.md §9)."""
 import json
 import os
+import subprocess
+import sys
 import warnings
+from pathlib import Path
 
 import jax
 import numpy as np
@@ -23,6 +26,7 @@ from repro.core import FedAvgTrainer, RuntimeModel, make_eval_fn
 from repro.core.engine import MeshBackend
 from repro.core.engine.trainer import History
 from repro.data import make_paper_task
+from repro.launch.mesh import make_mesh
 from repro.models import small
 
 
@@ -193,7 +197,7 @@ def _direct_trainer(spec: ExperimentSpec):
                       fed.clients_per_round)
     backend = None
     if spec.backend.name == "mesh":
-        mesh = jax.make_mesh((len(jax.devices()), 1), ("data", "model"))
+        mesh = make_mesh((len(jax.devices()), 1), ("data", "model"))
         backend = MeshBackend(mesh, strategy=spec.backend.strategy)
     eval_fn = (make_eval_fn(loss_fn, data) if spec.fed.eval_every else None)
     return FedAvgTrainer(loss_fn, params, data, fed, rt, eval_fn=eval_fn,
@@ -313,3 +317,35 @@ def test_history_from_dict_warns_on_unknown_fields():
     with warnings.catch_warnings():
         warnings.simplefilter("error")      # clean dicts stay silent
         History.from_dict(History().as_dict())
+
+
+@pytest.mark.parametrize("argv,reduced", [([], True), (["--reduced"], True),
+                                          (["--no-reduced"], False)])
+def test_legacy_reduced_flag_reaches_published_widths(argv, reduced):
+    from repro.launch.train import make_parser, spec_from_legacy_args
+    spec = spec_from_legacy_args(make_parser().parse_args(argv))
+    assert spec.model.reduced is reduced
+
+
+def test_compile_cache_placed_from_environment(tmp_path):
+    """The entry points' cache helper keeps the cache where
+    JAX_COMPILATION_CACHE_DIR says and sets no other directory; without it
+    the cache sits at a fixed path in the checkout."""
+    from repro.launch import compile_cache
+    repo = Path(__file__).resolve().parents[1]
+    assert compile_cache.DEFAULT_DIR == repo / ".jax_cache"
+
+    cache = tmp_path / "cc"
+    code = ("import jax, jax.numpy as jnp\n"
+            "from repro.launch.compile_cache import enable_compile_cache\n"
+            f"assert enable_compile_cache() == {str(cache)!r}\n"
+            f"assert jax.config.jax_compilation_cache_dir == {str(cache)!r}\n"
+            "jax.jit(lambda x: x * 2 + 1)(jnp.ones(3)).block_until_ready()\n")
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "JAX_COMPILATION_CACHE_DIR": str(cache),
+           "PYTHONPATH": str(repo / "src")}
+    r = subprocess.run([sys.executable, "-c", code], env=env, cwd=tmp_path,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr
+    assert any(cache.iterdir()), "no cache entry was written"
+    assert sorted(p.name for p in tmp_path.iterdir()) == ["cc"]

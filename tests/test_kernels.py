@@ -1,4 +1,8 @@
 """Per-kernel shape/dtype sweeps vs the pure-jnp oracles (interpret=True)."""
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -164,7 +168,7 @@ def test_moe_ffn_kernel_matches_oracle():
 # ---------------------------------------------------------------------------
 
 from repro.kernels import delta_codec as dc          # noqa: E402
-from repro.launch.mesh import make_host_mesh         # noqa: E402
+from repro.launch.mesh import make_host_mesh, make_mesh  # noqa: E402
 
 
 def _topk_payload(seed, n, k, m):
@@ -245,11 +249,28 @@ def test_topk_scatter_sharded_matches_unsharded():
                                rtol=1e-6, atol=1e-6)
 
 
+def test_import_picks_no_backend():
+    """Importing the package must not initialise a JAX backend: on a TPU
+    host that would take the chip from whichever process runs next. The
+    interpret-mode decision is made per kernel call instead."""
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    code = ("import repro, repro.kernels, repro.api, repro.core, "
+            "repro.launch.train, repro.launch.fleet\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge.backends_are_initialized()\n"
+            "from repro.kernels import ops\n"
+            "assert ops.interpret_mode()\n")
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": src}
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr
+
+
 def test_mosaic_scatter_dispatch_gate():
     """ops.topk_delta_reduce picks Mosaic for small dense work volumes and
     the XLA oracle beyond the interpret-mode ceiling — both must agree."""
     assert ops.mosaic_scatter_ok(8, 100)
-    if ops.INTERPRET:
+    if ops.interpret_mode():
         assert not ops.mosaic_scatter_ok(1 << 12, 1 << 12)
     vals, idx, w = _topk_payload(0, 4, 16, 333)
     out = ops.topk_delta_reduce(vals, idx, w, 333)
@@ -265,7 +286,7 @@ def test_mosaic_scatter_dispatch_gate():
 def _pod_data_mesh():
     """Two client axes on one host device — exercises the grouped-axes
     collective lowering without needing multiple devices."""
-    return jax.make_mesh((1, 1), ("pod", "data"))
+    return make_mesh((1, 1), ("pod", "data"))
 
 
 def test_psum_tiers_rejects_non_partition():

@@ -194,6 +194,33 @@ def test_chunked_peak_memory_budget():
         f"peak {dense_mb:.2f}MB -> {chunk_mb:.2f}MB: reduction under 4x"
 
 
+def test_chunked_round_frees_slab_buffers_before_finalize():
+    """Across a streamed round, the only params-sized device buffers that
+    stay live are the two f32 accumulators: the last slab's inputs (the
+    previous accumulators) and its pass-through EF residual are freed before
+    the finalize allocates, else a full-width model runs out of HBM there."""
+    exp = build(_spec(1, "int8"))
+    engine = exp.trainer.engine
+    param_bytes = sum(x.size * 4 for x in jax.tree.leaves(exp.params))
+    live = {}
+    lookup = engine._lookup
+
+    def counting_lookup(key, jitted, args):
+        exe = lookup(key, jitted, args)
+
+        def call(*a):
+            live.setdefault(key[0], sum(x.nbytes for x in jax.live_arrays()))
+            return exe(*a)
+        return call
+
+    engine._lookup = counting_lookup
+    exp.run(1)
+    # first slab: params, the shared zero accumulator, the EF residual;
+    # finalize: params, two accumulators, the EF residual
+    growth = (live["slabfin"] - live["slab"]) / param_bytes
+    assert growth <= 1.5, f"{growth:.2f} extra params-sized buffers"
+
+
 # ---------------------------------------------------------------------------
 # checkpoints: mid-round slab state never persists
 # ---------------------------------------------------------------------------
