@@ -13,6 +13,7 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from repro.core import obs
 from repro.core.engine.aggregators import Aggregator, get_aggregator
 from repro.core.engine.backends.base import ExecutionBackend, LossFn
 from repro.core.engine.client import make_client_update
@@ -67,10 +68,11 @@ def make_parallel_round_core(loss_fn: LossFn, aggregator: Aggregator,
                 client_params, first_losses, last_losses = jax.vmap(
                     client, in_axes=(None, 0, None),
                     spmd_axis_name=client_spmd_axes)(params, batches, eta)
-                aggregate = aggregator(client_params, weights)
-                new_params, server_state = server.step(params, aggregate,
-                                                       server_state,
-                                                       server_lr)
+                with obs.scope("uplink.reduce"):
+                    aggregate = aggregator(client_params, weights)
+                with obs.scope("server.step"):
+                    new_params, server_state = server.step(
+                        params, aggregate, server_state, server_lr)
                 return new_params, first_losses, last_losses, server_state
 
             return round_core
@@ -81,8 +83,9 @@ def make_parallel_round_core(loss_fn: LossFn, aggregator: Aggregator,
                 spmd_axis_name=client_spmd_axes)(params, batches, eta)
             aggregate, t_state = transport.aggregate(
                 aggregator, params, client_params, weights, t_state)
-            new_params, server_state = server.step(params, aggregate,
-                                                   server_state, server_lr)
+            with obs.scope("server.step"):
+                new_params, server_state = server.step(
+                    params, aggregate, server_state, server_lr)
             return (new_params, first_losses, last_losses, server_state,
                     t_state)
 
@@ -97,23 +100,27 @@ def make_parallel_round_core(loss_fn: LossFn, aggregator: Aggregator,
     def d_core(params, batches, weights, eta, server_state, extra):
         t_state, d_state = (extra if transport is not None
                             else (None, extra))
-        ref, payload, recon, d_state, level = encode_broadcast(
-            downlink, params, d_state)
+        with obs.scope("server.step"):   # the server encodes its broadcast
+            ref, payload, recon, d_state, level = encode_broadcast(
+                downlink, params, d_state)
         if constrain is not None:
             recon = constrain(recon)
         client_params, first_losses, last_losses = jax.vmap(
             fused, in_axes=(None, 0, None),
             spmd_axis_name=client_spmd_axes)((ref, payload), batches, eta)
         if transport is None:
-            aggregate = aggregator(client_params, weights)
-            new_params, server_state = server.step(recon, aggregate,
-                                                   server_state, server_lr)
+            with obs.scope("uplink.reduce"):
+                aggregate = aggregator(client_params, weights)
+            with obs.scope("server.step"):
+                new_params, server_state = server.step(
+                    recon, aggregate, server_state, server_lr)
             return (new_params, first_losses, last_losses, server_state,
                     d_state, level)
         aggregate, t_state = transport.aggregate(
             aggregator, recon, client_params, weights, t_state)
-        new_params, server_state = server.step(recon, aggregate,
-                                               server_state, server_lr)
+        with obs.scope("server.step"):
+            new_params, server_state = server.step(recon, aggregate,
+                                                   server_state, server_lr)
         return (new_params, first_losses, last_losses, server_state,
                 (t_state, d_state), level)
 
@@ -154,35 +161,39 @@ def make_parallel_slab_cores(loss_fn: LossFn, aggregator: Aggregator,
             spmd_axis_name=client_spmd_axes)(params, batches, eta)
         hat_acc, true_acc = acc
         if transport is None:
-            part = aggregator(client_params, weights)
-            hat_acc = jax.tree.map(
-                lambda a, p: a + p.astype(jnp.float32), hat_acc, part)
+            with obs.scope("uplink.reduce"):
+                part = aggregator(client_params, weights)
+                hat_acc = jax.tree.map(
+                    lambda a, p: a + p.astype(jnp.float32), hat_acc, part)
             return (hat_acc, true_acc), first_losses, last_losses, ef
         hat, true, ef = transport.aggregate_slab(
             params, client_params, weights, ef)
-        hat_acc = jax.tree.map(jnp.add, hat_acc, hat)
-        if agg_ef:
-            true_acc = jax.tree.map(jnp.add, true_acc, true)
+        with obs.scope("uplink.reduce"):
+            hat_acc = jax.tree.map(jnp.add, hat_acc, hat)
+            if agg_ef:
+                true_acc = jax.tree.map(jnp.add, true_acc, true)
         return (hat_acc, true_acc), first_losses, last_losses, ef
 
     def finalize_core(params, acc, server_state):
-        hat_acc, true_acc = acc
-        if transport is None:
-            # hat_acc holds sum_slabs aggregator(...) in f32; the cast is
-            # the dense path's own einsum->dtype cast, deferred to round end
-            aggregate = jax.tree.map(lambda a, p: a.astype(p.dtype),
-                                     hat_acc, params)
+        with obs.scope("server.step"):
+            hat_acc, true_acc = acc
+            if transport is None:
+                # hat_acc holds sum_slabs aggregator(...) in f32; the cast
+                # is the dense path's own einsum->dtype cast, deferred to
+                # round end
+                aggregate = jax.tree.map(lambda a, p: a.astype(p.dtype),
+                                         hat_acc, params)
+                new_params, server_state = server.step(
+                    params, aggregate, server_state, server_lr)
+                return new_params, server_state, ()
+            aggregate = jax.tree.map(
+                lambda p, h: (p.astype(jnp.float32) + h).astype(p.dtype),
+                params, hat_acc)
             new_params, server_state = server.step(params, aggregate,
                                                    server_state, server_lr)
-            return new_params, server_state, ()
-        aggregate = jax.tree.map(
-            lambda p, h: (p.astype(jnp.float32) + h).astype(p.dtype),
-            params, hat_acc)
-        new_params, server_state = server.step(params, aggregate,
-                                               server_state, server_lr)
-        new_res = (jax.tree.map(jnp.subtract, true_acc, hat_acc)
-                   if agg_ef else ())
-        return new_params, server_state, new_res
+            new_res = (jax.tree.map(jnp.subtract, true_acc, hat_acc)
+                       if agg_ef else ())
+            return new_params, server_state, new_res
 
     return slab_core, finalize_core
 

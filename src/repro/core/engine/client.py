@@ -15,6 +15,8 @@ from typing import Any, Callable, Dict, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import obs
+
 PyTree = Any
 LossFn = Callable[[PyTree, Dict[str, jnp.ndarray]], Any]
 
@@ -42,16 +44,16 @@ def client_update(loss_fn: LossFn, params: PyTree,
     its own round-start model inside its own trace, so the engine never
     materialises the decoded f32 tree as a separate round input.
     """
-    if reconstruct is not None:
-        params = reconstruct(params)
-
     def step(p, batch):
         (loss, _), grads = jax.value_and_grad(loss_fn, has_aux=True)(p, batch)
         p = jax.tree.map(lambda w, g: (w - eta * g).astype(w.dtype), p, grads)
         return p, loss
 
-    final, losses = jax.lax.scan(step, params, client_batches)
-    return ClientResult(final, losses[0], losses[-1])
+    with obs.scope("client.step"):
+        if reconstruct is not None:
+            params = reconstruct(params)
+        final, losses = jax.lax.scan(step, params, client_batches)
+        return ClientResult(final, losses[0], losses[-1])
 
 
 def make_client_update(loss_fn: LossFn, reconstruct: Any = None):

@@ -25,11 +25,13 @@ so padding never perturbs training state.
 from __future__ import annotations
 
 import threading
+import time
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from repro.core import obs
 from repro.core.engine.aggregators import Aggregator, get_aggregator
 from repro.core.engine.backends.base import (ExecutionBackend,
                                              LINEAR_AGGREGATORS)
@@ -62,10 +64,11 @@ def make_bucket_fn(round_core):
             params, state = carry
             b, w, eta, act = xs
             new_p, first, last, new_s = round_core(params, b, w, eta, state)
-            new_p = jax.tree.map(lambda n, o: jnp.where(act, n, o),
-                                 new_p, params)
-            new_s = jax.tree.map(lambda n, o: jnp.where(act, n, o),
-                                 new_s, state)
+            with obs.scope("server.step"):
+                new_p = jax.tree.map(lambda n, o: jnp.where(act, n, o),
+                                     new_p, params)
+                new_s = jax.tree.map(lambda n, o: jnp.where(act, n, o),
+                                     new_s, state)
             return (new_p, new_s), (first, last)
 
         (params, server_state), (firsts, lasts) = jax.lax.scan(
@@ -92,9 +95,10 @@ def make_transport_bucket_fn(round_core):
             new_p, first, last, new_s, new_t = round_core(
                 params, b, w, eta, state, tstate)
             sel = lambda n, o: jnp.where(act, n, o)
-            new_p = jax.tree.map(sel, new_p, params)
-            new_s = jax.tree.map(sel, new_s, state)
-            new_t = jax.tree.map(sel, new_t, tstate)
+            with obs.scope("server.step"):
+                new_p = jax.tree.map(sel, new_p, params)
+                new_s = jax.tree.map(sel, new_s, state)
+                new_t = jax.tree.map(sel, new_t, tstate)
             return (new_p, new_s, new_t), (first, last)
 
         (params, server_state, t_state), (firsts, lasts) = jax.lax.scan(
@@ -126,10 +130,11 @@ def make_downlink_bucket_fn(round_core):
             new_p, first, last, new_s, new_e, level = round_core(
                 params, b, w, eta, state, ex)
             sel = lambda n, o: jnp.where(act, n, o)
-            new_p = jax.tree.map(sel, new_p, params)
-            new_s = jax.tree.map(sel, new_s, state)
-            new_e = jax.tree.map(sel, new_e, ex)
-            level = jnp.where(act, level, jnp.int32(-1))
+            with obs.scope("server.step"):
+                new_p = jax.tree.map(sel, new_p, params)
+                new_s = jax.tree.map(sel, new_s, state)
+                new_e = jax.tree.map(sel, new_e, ex)
+                level = jnp.where(act, level, jnp.int32(-1))
             return (new_p, new_s, new_e), (first, last, level)
 
         (params, server_state, extra), (firsts, lasts, levels) = jax.lax.scan(
@@ -161,15 +166,13 @@ class ExecutableRegistry:
     ``get_or_build`` is thread-safe and single-flight: when packed sweep
     points race on one key, exactly one thread compiles while the rest wait
     on the in-flight event — "compile once, dispatch N" holds under
-    concurrent packing, and the reuse counters stay exact.
+    concurrent packing.
     """
 
     def __init__(self):
         self._entries: Dict[Tuple, Any] = {}
         self._inflight: Dict[Tuple, threading.Event] = {}
         self._lock = threading.Lock()
-        self.hits = 0          # lookups served from an existing entry
-        self.misses = 0        # lookups that compiled a new entry
 
     def __len__(self) -> int:
         return len(self._entries)
@@ -198,7 +201,6 @@ class ExecutableRegistry:
             with self._lock:
                 exe = self._entries.get(key)
                 if exe is not None:
-                    self.hits += 1
                     return exe, False
                 ev = self._inflight.get(key)
                 if ev is None:
@@ -216,7 +218,6 @@ class ExecutableRegistry:
         with self._lock:
             self._entries[key] = exe
             del self._inflight[key]
-            self.misses += 1
         ev.set()
         return exe, True
 
@@ -397,6 +398,9 @@ class RoundEngine:
         self._own_keys: set = set()     # compiled by THIS engine
         self._shared_keys: set = set()  # adopted from the shared registry
         self.dispatch_count = 0
+        # host seconds spent in the round executables' calls: where the
+        # host blocks when the device has no memory for the next call yet
+        self.dispatch_s = 0.0
         # wire-state ownership lives in a GlobalModelStore (DESIGN.md §14);
         # the engine starts with a private one and the trainer re-binds its
         # own via bind_store(). transport_state/downlink_state stay
@@ -457,6 +461,15 @@ class RoundEngine:
             (self._own_keys if built else self._shared_keys).add(full_key)
         return exe
 
+    def _call(self, name: str, exe, args):
+        """Run one round executable under the host span ``name``, adding
+        its host time to ``dispatch_s``."""
+        t = time.perf_counter()
+        with obs.span(name):
+            out = exe(*args)
+        self.dispatch_s += time.perf_counter() - t
+        return out
+
     def init_server_state(self, params: PyTree) -> Any:
         return self.server.init(params)
 
@@ -512,7 +525,7 @@ class RoundEngine:
         key = (self._codec_sig,) + _signature(args)
         exe = self._lookup(key, self._jitted, args)
         self.dispatch_count += 1
-        out = exe(*args)
+        out = self._call("bucket.call", exe, args)
         if not has_t and not has_d:
             return out
         if has_d:
@@ -567,18 +580,19 @@ class RoundEngine:
         eta = jnp.asarray(eta, jnp.float32)
         firsts, lasts, ef_parts = [], [], []
         for sb in slabs:
-            sb = be.place_slab(sb)
-            ef = ()
-            if per_client:
-                ef = be.place_transport_state(
-                    jax.tree.map(lambda s: s[sb.start:sb.stop],
-                                 self.transport_state), per_client=True)
-            elif agg_ef:
-                ef = be.place_transport_state(self.transport_state)
+            with obs.span("slab.place"):
+                sb = be.place_slab(sb)
+                ef = ()
+                if per_client:
+                    ef = be.place_transport_state(
+                        jax.tree.map(lambda s: s[sb.start:sb.stop],
+                                     self.transport_state), per_client=True)
+                elif agg_ef:
+                    ef = be.place_transport_state(self.transport_state)
             args = (params, sb.batches, sb.weights, eta, acc, ef)
             key = ("slab", self._codec_sig) + _signature(args)
             exe = self._lookup(key, self._jit_slab, args)
-            acc, f, l, ef = exe(*args)
+            acc, f, l, ef = self._call("slab.call", exe, args)
             firsts.append(f)
             lasts.append(l)
             if per_client:
@@ -589,7 +603,8 @@ class RoundEngine:
         fargs = (params, acc, server_state)
         key = ("slabfin", self._codec_sig) + _signature(fargs)
         exe = self._lookup(key, self._jit_slabfin, fargs)
-        new_params, server_state, new_res = exe(*fargs)
+        new_params, server_state, new_res = self._call("finalize.call", exe,
+                                                       fargs)
         if per_client:
             self.transport_state = jax.tree.map(
                 lambda *xs: jnp.concatenate(xs, axis=0), *ef_parts)

@@ -19,6 +19,7 @@ Evaluation happens at bucket boundaries; the scheduler cuts buckets at
 from __future__ import annotations
 
 import dataclasses
+import time
 import warnings
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -28,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import FedConfig
+from repro.core import obs
 from repro.core.engine.backends.base import LINEAR_AGGREGATORS
 from repro.core.engine.model_store import GlobalModelStore
 from repro.core.engine.round import LossFn, RoundEngine
@@ -205,6 +207,8 @@ class FedAvgTrainer:
         # ticks it at bucket boundaries (DESIGN.md §14)
         self.serving = None
         self.serve_every = 0
+        # host seconds the trainer's thread waited on the batch builder
+        self.feed_wait_s = 0.0
 
     # ------------------------------------------------------------------
     # state delegation: the GlobalModelStore owns it, the historical
@@ -241,6 +245,11 @@ class FedAvgTrainer:
     @property
     def dispatch_count(self) -> int:
         return self.engine.dispatch_count
+
+    @property
+    def dispatch_s(self) -> float:
+        """Host seconds spent in the round executables' calls."""
+        return self.engine.dispatch_s
 
     def run(self, rounds: Optional[int] = None, eval_every: int = 10,
             verbose: bool = False, resume: bool = False) -> History:
@@ -316,9 +325,18 @@ class FedAvgTrainer:
                            rounds=bucket.rounds)
 
     def _pull_dispatch(self, bucket: Bucket, builder):
-        if getattr(self.fed, "cohort_chunk", None):
-            return self._dispatch_chunked(bucket, builder)
-        return self._dispatch(bucket, builder.get())
+        with obs.span("round.dispatch"):
+            if getattr(self.fed, "cohort_chunk", None):
+                return self._dispatch_chunked(bucket, builder)
+            return self._dispatch(bucket, self._get(builder))
+
+    def _get(self, builder):
+        """The builder's next item, its wait added to ``feed_wait_s``."""
+        t = time.perf_counter()
+        with obs.span("feed.wait"):
+            item = builder.get()
+        self.feed_wait_s += time.perf_counter() - t
+        return item
 
     def _dispatch_chunked(self, bucket: Bucket, builder):
         """One streaming round (the scheduler forces 1-round buckets under
@@ -331,7 +349,7 @@ class FedAvgTrainer:
 
         def slabs():
             for _ in range(n_slabs):
-                yield builder.get()
+                yield self._get(builder)
 
         self.params, firsts, _lasts, self.server_state = \
             self.engine.run_round_chunked(self.params, slabs(),
@@ -385,33 +403,35 @@ class FedAvgTrainer:
         ``levels``: the bucket's (B,) adaptive downlink levels — only
         supplied (by ``_dispatch``) when the runtime carries per-level
         ratios, so fixed-rate codecs keep the historical charge exactly."""
-        losses = np.asarray(firsts)               # device sync
-        lv = None if levels is None else np.asarray(levels)
-        h = self.history
-        for i, r in enumerate(bucket.rounds):
-            round_loss = float(np.mean(losses[i]))
-            self.ctrl.observe_round_losses(round_loss)
-            cost = self.runtime.round_cost(
-                bucket.k,
-                downlink_level=None if lv is None else int(lv[i]))
-            self._wall += cost.wall_clock_s
-            self._steps += cost.sgd_steps
-            self._up_mbit += cost.uplink_mbit
-            self._down_mbit += cost.downlink_mbit
-            self.store.serve_queries += cost.serve_queries
-            self._min_loss = min(self._min_loss, round_loss)
-            h.rounds.append(r)
-            h.k.append(bucket.k)
-            h.eta.append(bucket.etas[i])
-            h.wall_clock_s.append(self._wall)
-            h.sgd_steps.append(self._steps)
-            h.uplink_mbit.append(self._up_mbit)
-            h.downlink_mbit.append(self._down_mbit)
-            h.train_loss.append(round_loss)
-            h.min_train_loss.append(self._min_loss)
-            if (self.serving is not None and self.serve_every
-                    and r % self.serve_every == 0):
-                self.serving.tick(r, h)
+        with obs.span("round.absorb"):
+            with obs.span("loss.sync"):
+                losses = np.asarray(firsts)       # device sync
+            lv = None if levels is None else np.asarray(levels)
+            h = self.history
+            for i, r in enumerate(bucket.rounds):
+                round_loss = float(np.mean(losses[i]))
+                self.ctrl.observe_round_losses(round_loss)
+                cost = self.runtime.round_cost(
+                    bucket.k,
+                    downlink_level=None if lv is None else int(lv[i]))
+                self._wall += cost.wall_clock_s
+                self._steps += cost.sgd_steps
+                self._up_mbit += cost.uplink_mbit
+                self._down_mbit += cost.downlink_mbit
+                self.store.serve_queries += cost.serve_queries
+                self._min_loss = min(self._min_loss, round_loss)
+                h.rounds.append(r)
+                h.k.append(bucket.k)
+                h.eta.append(bucket.etas[i])
+                h.wall_clock_s.append(self._wall)
+                h.sgd_steps.append(self._steps)
+                h.uplink_mbit.append(self._up_mbit)
+                h.downlink_mbit.append(self._down_mbit)
+                h.train_loss.append(round_loss)
+                h.min_train_loss.append(self._min_loss)
+                if (self.serving is not None and self.serve_every
+                        and r % self.serve_every == 0):
+                    self.serving.tick(r, h)
 
     # ------------------------------------------------------------------
     # full-state checkpointing (DESIGN.md §8: transport/EF state included)

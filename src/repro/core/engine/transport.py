@@ -67,6 +67,7 @@ import jax.numpy as jnp
 # literal reuse of the Q-KV quantisation scheme (two-level int8 + per-vector
 # f32 scales — models/attention.py §Perf Q-KV); pure jnp, no layer deps
 from repro.api.registries import TRANSPORT_REGISTRY, register_transport
+from repro.core import obs
 from repro.core.engine.backends.base import axes_size as _axes_size
 from repro.models.attention import quantize_kv, quantize_kv_residual
 
@@ -184,41 +185,48 @@ class Transport:
         (aggregate pytree, new state). Compressed codecs ignore the
         aggregator (validated linear upstream) and work in delta space."""
         del aggregator
-        p32 = jax.tree.map(lambda p: p.astype(jnp.float32), params)
-        deltas = jax.tree.map(lambda cp, p: cp.astype(jnp.float32) - p[None],
-                              client_stack, p32)
-        if self.error_feedback:
-            # compensate: per-client slots carry their own residual (fixed
-            # cohorts), the aggregate residual is broadcast to every client
-            deltas = (jax.tree.map(jnp.add, deltas, state) if self.ef_slots
-                      else jax.tree.map(lambda d, r: d + r[None], deltas,
-                                        state))
-        payloads = jax.vmap(self.encode)(deltas)
-        hat = self.reduce(payloads, weights, like=params)
-        if not self.error_feedback:
-            new_state = state
-        elif self.ef_slots:
-            # per-client residual: each slot keeps ITS OWN compression error
-            # (Karimireddy et al. '19, the stateful-client original) — no
-            # weighted-truth term, no cross-client mixing. The residual
-            # NEEDS the per-client decode, so this mode pays decode twice
-            # (once fused inside reduce, once here); hat deliberately stays
-            # on the fused reduce so the wire-aggregation program — and its
-            # numerics — are identical across EF modes (the parity
-            # contracts in tests/test_sampling.py key on this). Decode is
-            # O(N*M) elementwise, dwarfed by the K local-SGD steps.
-            decoded = jax.vmap(lambda pl: self.decode(pl, like=params))(
-                payloads)
-            new_state = jax.tree.map(jnp.subtract, deltas, decoded)
-        else:
-            true = _weighted_true_sum(jax.tree.leaves(deltas), weights)
-            new_state = jax.tree.unflatten(
-                jax.tree.structure(params),
-                [t - h for t, h in zip(true, jax.tree.leaves(hat))])
-        aggregate = jax.tree.map(
-            lambda p, h: (p.astype(jnp.float32) + h).astype(p.dtype),
-            params, hat)
-        return aggregate, new_state
+        with obs.scope("uplink.encode"):
+            p32 = jax.tree.map(lambda p: p.astype(jnp.float32), params)
+            deltas = jax.tree.map(
+                lambda cp, p: cp.astype(jnp.float32) - p[None],
+                client_stack, p32)
+            if self.error_feedback:
+                # compensate: per-client slots carry their own residual
+                # (fixed cohorts), the aggregate residual is broadcast to
+                # every client
+                deltas = (jax.tree.map(jnp.add, deltas, state)
+                          if self.ef_slots
+                          else jax.tree.map(lambda d, r: d + r[None], deltas,
+                                            state))
+            payloads = jax.vmap(self.encode)(deltas)
+            with obs.scope("uplink.reduce"):
+                hat = self.reduce(payloads, weights, like=params)
+            if not self.error_feedback:
+                new_state = state
+            elif self.ef_slots:
+                # per-client residual: each slot keeps ITS OWN compression
+                # error (Karimireddy et al. '19, the stateful-client
+                # original) — no weighted-truth term, no cross-client
+                # mixing. The residual NEEDS the per-client decode, so this
+                # mode pays decode twice (once fused inside reduce, once
+                # here); hat deliberately stays on the fused reduce so the
+                # wire-aggregation program — and its numerics — are
+                # identical across EF modes (the parity contracts in
+                # tests/test_sampling.py key on this). Decode is O(N*M)
+                # elementwise, dwarfed by the K local-SGD steps.
+                decoded = jax.vmap(lambda pl: self.decode(pl, like=params))(
+                    payloads)
+                new_state = jax.tree.map(jnp.subtract, deltas, decoded)
+            else:
+                true = _weighted_true_sum(jax.tree.leaves(deltas), weights)
+                new_state = jax.tree.unflatten(
+                    jax.tree.structure(params),
+                    [t - h for t, h in zip(true, jax.tree.leaves(hat))])
+            with obs.scope("uplink.reduce"):
+                aggregate = jax.tree.map(
+                    lambda p, h: (p.astype(jnp.float32) + h).astype(p.dtype),
+                    params, hat)
+            return aggregate, new_state
 
     def aggregate_slab(self, params: PyTree, client_stack: PyTree,
                        weights: jnp.ndarray, state):
@@ -239,24 +247,29 @@ class Transport:
         decoded deltas, ``true`` the f32 weighted-sum of raw deltas
         (aggregate-EF codecs only, else ``()``), ``new_state`` the slab's
         updated per-client residuals (slotted EF) or ``state`` unchanged."""
-        p32 = jax.tree.map(lambda p: p.astype(jnp.float32), params)
-        deltas = jax.tree.map(lambda cp, p: cp.astype(jnp.float32) - p[None],
-                              client_stack, p32)
-        if self.error_feedback:
-            deltas = (jax.tree.map(jnp.add, deltas, state) if self.ef_slots
-                      else jax.tree.map(lambda d, r: d + r[None], deltas,
-                                        state))
-        payloads = jax.vmap(self.encode)(deltas)
-        hat = self.reduce(payloads, weights, like=params)
-        if not self.error_feedback:
-            return hat, (), state
-        if self.ef_slots:
-            decoded = jax.vmap(lambda pl: self.decode(pl, like=params))(
-                payloads)
-            return hat, (), jax.tree.map(jnp.subtract, deltas, decoded)
-        true = _weighted_true_sum(jax.tree.leaves(deltas), weights)
-        true_tree = jax.tree.unflatten(jax.tree.structure(params), list(true))
-        return hat, true_tree, state
+        with obs.scope("uplink.encode"):
+            p32 = jax.tree.map(lambda p: p.astype(jnp.float32), params)
+            deltas = jax.tree.map(
+                lambda cp, p: cp.astype(jnp.float32) - p[None],
+                client_stack, p32)
+            if self.error_feedback:
+                deltas = (jax.tree.map(jnp.add, deltas, state)
+                          if self.ef_slots
+                          else jax.tree.map(lambda d, r: d + r[None], deltas,
+                                            state))
+            payloads = jax.vmap(self.encode)(deltas)
+            with obs.scope("uplink.reduce"):
+                hat = self.reduce(payloads, weights, like=params)
+            if not self.error_feedback:
+                return hat, (), state
+            if self.ef_slots:
+                decoded = jax.vmap(lambda pl: self.decode(pl, like=params))(
+                    payloads)
+                return hat, (), jax.tree.map(jnp.subtract, deltas, decoded)
+            true = _weighted_true_sum(jax.tree.leaves(deltas), weights)
+            true_tree = jax.tree.unflatten(jax.tree.structure(params),
+                                           list(true))
+            return hat, true_tree, state
 
 
 class IdentityTransport(Transport):

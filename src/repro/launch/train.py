@@ -246,9 +246,13 @@ def main(argv=None):
               f"per client-round)")
 
     h = exp.run()
+    # the synchronous trainer's host counters (repro.core.obs spans)
+    host = ("" if spec.fed.aggregation == "async" else
+            f"; per round {1e3 * trainer.feed_wait_s / rounds:.2f} ms feed "
+            f"wait, {1e3 * trainer.dispatch_s / rounds:.2f} ms in dispatch")
     print(f"[train] engine[{spec.backend.name}]: {trainer.compile_count} "
           f"bucket executable(s) compiled, {trainer.dispatch_count} "
-          f"dispatch(es) for {rounds} rounds")
+          f"dispatch(es) for {rounds} rounds{host}")
     if spec.fed.aggregation == "async":
         print(f"[train] async: {trainer.applied_updates} updates applied, "
               f"{trainer.dropped_updates} dropped, mean staleness "
