@@ -349,17 +349,20 @@ class Int8Transport(Transport):
         leaves, treedef = jax.tree.flatten(like)
         out = []
         for pl, leaf in zip(payloads, leaves):
+            # the flat payloads in the leaf's shape: the kernel takes its
+            # lanes from the last dimension and returns the leaf's shape
+            stack = lambda x: x.reshape((n,) + leaf.shape)
             w1 = weights.astype(jnp.float32) * pl["s"][:, 0]
             wr = (weights.astype(jnp.float32) * pl["rs"][:, 0]
                   if self.levels == 2 else None)
-            qr = pl["qr"] if self.levels == 2 else None
+            qr = stack(pl["qr"]) if self.levels == 2 else None
             if sharded:
-                flat = kops.int8_delta_reduce_sharded(
-                    pl["q"], w1, qr, wr, mesh=mesh, client_axes=axes,
+                hat = kops.int8_delta_reduce_sharded(
+                    stack(pl["q"]), w1, qr, wr, mesh=mesh, client_axes=axes,
                     reduce_tiers=self._tiers())
             else:
-                flat = kops.int8_delta_reduce(pl["q"], w1, qr, wr)
-            out.append(flat.reshape(leaf.shape))
+                hat = kops.int8_delta_reduce(stack(pl["q"]), w1, qr, wr)
+            out.append(hat)
         return jax.tree.unflatten(treedef, out)
 
     def decode_apply(self, payload, ref):

@@ -6,8 +6,13 @@ Decoding each client to f32 before reducing would materialise the full
 (N, M) f32 stack again — exactly the buffer compression was meant to kill.
 These kernels fuse dequantisation into the weighted block-reduce of
 ``fedavg_reduce``: the int8 payload is the only HBM-resident client stack,
-the f32 decode happens per (N x BM) VMEM block, and one (M,) f32 output is
-written.
+the f32 decode happens per VMEM block, and one f32 output is written. They
+share its block layout (``fedavg_reduce.reduce_tiling``): a stack of
+client payloads in the leaf's shape, (N, *shape), is viewed as lane-dense
+(N, R, L) rows, L the leaf's last dimension where it is a multiple of 128,
+and reduced in (N, BR, L) blocks of 32-row int8 tiles, BR set by N and a
+VMEM budget, on a ``cdiv`` grid whose last block may be partial. The
+result comes back in the leaf's shape with no relayout.
 
 Per-leaf int8 payloads carry a scalar scale per level, so the per-client
 dequantise-and-weight factor folds into the weight column:
@@ -62,86 +67,51 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.sharding import PartitionSpec as P
 
-from repro.kernels.fedavg_reduce import (DEFAULT_BLOCK, _block_reduce,
+from repro.kernels.fedavg_reduce import (_block_reduce, _client_spec,
                                          psum_tiers)
 
-
-def _kernel2(w_ref, wr_ref, q_ref, qr_ref, o_ref):
-    q = q_ref[...].astype(jnp.float32)            # (N, BM) primary plane
-    qr = qr_ref[...].astype(jnp.float32)          # (N, BM) residual plane
-    o_ref[...] = (jnp.sum(q * w_ref[...], axis=0, keepdims=True)
-                  + jnp.sum(qr * wr_ref[...], axis=0, keepdims=True))
+#: downlink decode-apply block: (1, DEFAULT_BLOCK) rows of the flat vector
+DEFAULT_BLOCK = 4096
 
 
-def _block_reduce2(q, qr, w, wr, block, interpret):
-    """Two-plane (N, M) int8 x (N,) f32 -> (M,) f32, one fused pass."""
-    n, m = q.shape
-    pad = (-m) % block
-    if pad:
-        q = jnp.pad(q, ((0, 0), (0, pad)))
-        qr = jnp.pad(qr, ((0, 0), (0, pad)))
-    mp = m + pad
-    out = pl.pallas_call(
-        _kernel2,
-        grid=(mp // block,),
-        in_specs=[
-            pl.BlockSpec((n, 1), lambda i: (0, 0)),      # w * scale column
-            pl.BlockSpec((n, 1), lambda i: (0, 0)),      # w * rscale column
-            pl.BlockSpec((n, block), lambda i: (0, i)),  # primary int8 block
-            pl.BlockSpec((n, block), lambda i: (0, i)),  # residual int8 block
-        ],
-        out_specs=pl.BlockSpec((1, block), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, mp), jnp.float32),
-        interpret=interpret,
-    )(w[:, None].astype(jnp.float32), wr[:, None].astype(jnp.float32), q, qr)
-    return out[0, :m]
-
-
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def int8_decompress_reduce(q, w_eff, qr=None, wr_eff=None, *,
-                           block: int = DEFAULT_BLOCK,
                            interpret: bool = False) -> jnp.ndarray:
-    """q (N, M) int8; w_eff (N,) = weights * per-client scales -> (M,) f32.
+    """q (N, *shape) int8; w_eff (N,) = weights * per-client scales -> shape
+    f32, e.g. (N, M) -> (M,). Pass the payloads in the leaf's shape: its
+    last dimension then sets the lanes and the result needs no relayout.
 
     With the optional residual plane ``qr``/``wr_eff`` the two dequantise-
     weight-reduce passes fuse into one kernel invocation per block.
     """
-    if qr is None:
-        return _block_reduce(q, w_eff.astype(jnp.float32), block, interpret,
-                             out_dtype=jnp.float32)
-    return _block_reduce2(q, qr, w_eff, wr_eff, block, interpret)
+    planes, weights = ((q,), (w_eff,)) if qr is None else ((q, qr),
+                                                           (w_eff, wr_eff))
+    return _block_reduce(planes, weights, interpret, out_dtype=jnp.float32)
 
 
 def int8_decompress_reduce_sharded(q, w_eff, qr=None, wr_eff=None, *, mesh,
-                                   client_axes, block: int = DEFAULT_BLOCK,
-                                   interpret: bool = False,
+                                   client_axes, interpret: bool = False,
                                    reduce_tiers=None) -> jnp.ndarray:
     """Mesh variant (extends ``fedavg_reduce_sharded``): the int8 stack is
     sharded over ``client_axes``; per-shard fused decompress-reduce + one
-    all-reduce of the f32 (M,) partials (``psum_tiers``: flat or the
+    all-reduce of the f32 partials (``psum_tiers``: flat or the
     hierarchical grouped reduce). N must divide the axes' size."""
     axes = tuple(client_axes)
+    planes = (q,) if qr is None else (q, qr)
+    weights = (w_eff,) if qr is None else (w_eff, wr_eff)
 
-    if qr is None:
-        def local(x, w):
-            partial = _block_reduce(x, w.astype(jnp.float32), block,
-                                    interpret, out_dtype=jnp.float32)
-            return psum_tiers(partial, axes, reduce_tiers)
-
-        # check_vma=False: pallas_call has no varying-axes rule; the psum
-        # makes the P() out_spec replication explicit (as fedavg_reduce)
-        return jax.shard_map(local, mesh=mesh,
-                             in_specs=(P(axes, None), P(axes)),
-                             out_specs=P(), check_vma=False)(q, w_eff)
-
-    def local(x, xr, w, wr):
-        partial = _block_reduce2(x, xr, w, wr, block, interpret)
+    def local(planes, weights):
+        partial = _block_reduce(planes, weights, interpret,
+                                out_dtype=jnp.float32)
         return psum_tiers(partial, axes, reduce_tiers)
 
-    return jax.shard_map(local, mesh=mesh,
-                         in_specs=(P(axes, None), P(axes, None), P(axes),
-                                   P(axes)),
-                         out_specs=P(), check_vma=False)(q, qr, w_eff, wr_eff)
+    # check_vma=False: pallas_call has no varying-axes rule; the psum makes
+    # the P() out_spec replication explicit (as fedavg_reduce)
+    return jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(tuple(_client_spec(axes, x) for x in planes),
+                  tuple(P(axes) for _ in weights)),
+        out_specs=P(), check_vma=False)(planes, weights)
 
 
 # ---------------------------------------------------------------------------

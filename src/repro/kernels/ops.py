@@ -37,27 +37,24 @@ def interpret_mode() -> bool:
 # ---------------------------------------------------------------------------
 
 def fedavg_reduce(client_stack: jnp.ndarray, weights: jnp.ndarray) -> jnp.ndarray:
-    """(N, M) x (N,) -> (M,)."""
+    """(N, *shape) x (N,) -> shape, e.g. (N, M) -> (M,)."""
     return _fr.fedavg_reduce(client_stack, weights, interpret=interpret_mode())
 
 
 def fedavg_reduce_tree(client_params: PyTree, weights: jnp.ndarray) -> PyTree:
     """Weighted-average every leaf of a client-stacked param pytree.
 
-    Leaves have a leading client axis: (N, ...) -> (...).
+    Leaves have a leading client axis: (N, ...) -> (...). Each leaf goes
+    to the kernel in its own shape, so its last dimension sets the lanes.
     """
-    def one(leaf):
-        n = leaf.shape[0]
-        flat = leaf.reshape(n, -1)
-        return fedavg_reduce(flat, weights).reshape(leaf.shape[1:])
-
-    return jax.tree.map(one, client_params)
+    return jax.tree.map(lambda leaf: fedavg_reduce(leaf, weights),
+                        client_params)
 
 
 def fedavg_reduce_sharded(client_stack: jnp.ndarray, weights: jnp.ndarray, *,
                           mesh, client_axes,
                           reduce_tiers=None) -> jnp.ndarray:
-    """(N, M) x (N,) -> (M,), N sharded over the mesh client axes: local
+    """(N, *shape) x (N,) -> shape, N sharded over the mesh client axes: local
     Pallas block-reduce per shard + all-reduce of the f32 partials
     (``reduce_tiers`` selects the hierarchical grouped reduce, §11)."""
     return _fr.fedavg_reduce_sharded(client_stack, weights, mesh=mesh,
@@ -71,15 +68,11 @@ def fedavg_reduce_tree_sharded(client_params: PyTree, weights: jnp.ndarray,
                                reduce_tiers=None) -> PyTree:
     """Sharded weighted average of a client-stacked pytree (MeshBackend's
     ``aggregator="kernel"`` path — see DESIGN.md §7)."""
-    def one(leaf):
-        n = leaf.shape[0]
-        flat = leaf.reshape(n, -1)
-        return fedavg_reduce_sharded(flat, weights, mesh=mesh,
-                                     client_axes=client_axes,
-                                     reduce_tiers=reduce_tiers
-                                     ).reshape(leaf.shape[1:])
-
-    return jax.tree.map(one, client_params)
+    return jax.tree.map(
+        lambda leaf: fedavg_reduce_sharded(leaf, weights, mesh=mesh,
+                                           client_axes=client_axes,
+                                           reduce_tiers=reduce_tiers),
+        client_params)
 
 
 # ---------------------------------------------------------------------------
@@ -88,8 +81,10 @@ def fedavg_reduce_tree_sharded(client_params: PyTree, weights: jnp.ndarray,
 
 def int8_delta_reduce(q, w_eff, qr=None, wr_eff=None) -> jnp.ndarray:
     """Fused dequantise + weighted reduce of an int8 client-delta stack:
-    q (N, M) int8, w_eff (N,) = weights * per-client scales -> (M,) f32.
-    Optional residual plane (two-level codec) fuses into the same pass."""
+    q (N, *shape) int8, w_eff (N,) = weights * per-client scales -> shape
+    f32. Give q in the leaf's shape: its last dimension sets the kernel's
+    lanes. Optional residual plane (two-level codec) fuses into the same
+    pass."""
     return _dc.int8_decompress_reduce(q, w_eff, qr, wr_eff,
                                       interpret=interpret_mode())
 

@@ -1,4 +1,5 @@
 """Per-kernel shape/dtype sweeps vs the pure-jnp oracles (interpret=True)."""
+import math
 import os
 import subprocess
 import sys
@@ -8,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import delta_codec as dc
 from repro.kernels import fedavg_reduce as fr
 from repro.kernels import flash_attention as fa
 from repro.kernels import moe_gmm as mg
@@ -38,6 +40,79 @@ def test_fedavg_reduce_convex_combination():
     w = jnp.array([0.25, 0.75])
     np.testing.assert_allclose(np.asarray(fr.fedavg_reduce(x, w, interpret=True)),
                                0.75, rtol=1e-6)
+
+
+#: per-client leaf shapes of the lane-dense reduce: a last dimension that
+#: is a multiple of 128 (the leaf's own lanes), a flat size whose only
+#: such divisor is 128, sizes that need padding, a row count that is not a
+#: multiple of the block rows, a single row
+REDUCE_SHAPES = [(40, 256), (3, 64, 384), (24, 80), (1000,), (130,),
+                 (1000, 1024), (1024,)]
+RAGGED_ROWS = (1000, 1024)
+
+
+def _reduce_case(kind, n, shape):
+    """(kernel output, einsum reference, N = 1 exact form, stack dtype).
+    The two-plane sum has no exact form: a backend may contract it into a
+    fused multiply-add."""
+    k = jax.random.split(jax.random.PRNGKey(n * 7919 + sum(shape)), 4)
+    w = jax.random.uniform(k[0], (n,), jnp.float32, 0.1, 1.0)
+    if kind in ("int8", "int8x2"):
+        q = jax.random.randint(k[1], (n,) + shape, -127, 128, jnp.int8)
+        q32 = q.astype(jnp.float32)
+        if kind == "int8":
+            return (dc.int8_decompress_reduce(q, w, interpret=True),
+                    jnp.einsum("c,c...->...", w, q32), w[0] * q32[0], q.dtype)
+        wr = jax.random.uniform(k[2], (n,), jnp.float32, 1e-3, 1e-2)
+        qr = jax.random.randint(k[3], (n,) + shape, -127, 128, jnp.int8)
+        qr32 = qr.astype(jnp.float32)
+        return (dc.int8_decompress_reduce(q, w, qr, wr, interpret=True),
+                jnp.einsum("c,c...->...", w, q32)
+                + jnp.einsum("c,c...->...", wr, qr32), None, q.dtype)
+    x = jax.random.normal(k[1], (n,) + shape, kind)
+    x32 = x.astype(jnp.float32)
+    return (fr.fedavg_reduce(x, w, interpret=True),
+            jnp.einsum("c,c...->...", w, x32).astype(kind),
+            (w[0] * x32[0]).astype(kind), x.dtype)
+
+
+@pytest.mark.parametrize("shape", REDUCE_SHAPES, ids=str)
+@pytest.mark.parametrize("n", [1, 2, 5])
+@pytest.mark.parametrize("kind", ["int8", "int8x2", jnp.float32,
+                                  jnp.bfloat16],
+                         ids=["int8", "int8x2", "f32", "bf16"])
+def test_lane_dense_reduce_matches_einsum(kind, n, shape):
+    out, want, exact, dtype = _reduce_case(kind, n, shape)
+    assert out.shape == shape
+    if shape == RAGGED_ROWS:          # the case does end in a partial block
+        planes = 2 * n if kind == "int8x2" else n
+        lanes, block_rows, pad = fr.reduce_tiling(
+            planes, math.prod(shape), dtype, shape[-1])
+        assert shape[0] % block_rows and pad == 0
+    if n == 1 and exact is not None:
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(exact))
+    tol = TOL[jnp.bfloat16 if kind == jnp.bfloat16 else jnp.float32]
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want, np.float32), **tol)
+
+
+def test_reduce_tiling_takes_every_qwen_leaf_lane_dense():
+    """Every leaf of qwen1.5-0.5b reduces in its own last dimension as
+    lanes, unpadded, in blocks of whole sublane tiles (or one block)."""
+    from repro.configs import get_arch
+    from repro.models import registry
+    shapes = jax.eval_shape(lambda: registry.init(jax.random.PRNGKey(0),
+                                                  get_arch("qwen1.5-0.5b")))
+    leaves = jax.tree.leaves(shapes)
+    assert len(leaves) == 14
+    for leaf in leaves:
+        m = math.prod(leaf.shape)
+        for n, dtype, tile in ((1, jnp.int8, 32), (25, jnp.float32, 8)):
+            lanes, block_rows, pad = fr.reduce_tiling(n, m, dtype,
+                                                      leaf.shape[-1])
+            assert (lanes, pad) == (leaf.shape[-1], 0), leaf.shape
+            assert (block_rows % tile == 0
+                    or block_rows == m // lanes), (leaf.shape, n)
 
 
 # ---------------------------------------------------------------------------
@@ -167,7 +242,6 @@ def test_moe_ffn_kernel_matches_oracle():
 # top-k scatter (Mosaic one-hot matmul, DESIGN.md §10)
 # ---------------------------------------------------------------------------
 
-from repro.kernels import delta_codec as dc          # noqa: E402
 from repro.launch.mesh import make_host_mesh, make_mesh  # noqa: E402
 
 

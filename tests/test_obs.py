@@ -10,6 +10,7 @@ import pytest
 
 from repro.api import ExperimentSpec, build
 from repro.core import obs
+from repro.kernels import fedavg_reduce
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmarks", "chip"))
@@ -34,19 +35,28 @@ def recorded(request, tmp_path_factory):
         "fed.batch_size=2", "fed.bucket_rounds=1", "transport.name=int8",
         f"fed.cohort_chunk={1 if path == 'streamed' else 'null'}",
         "fed.rounds=2")
-    exp = build(spec)
-    tr = exp.trainer
-    counts = [(tr.feed_wait_s, tr.dispatch_s)]
-    for rounds in (1, 2):       # the first compiles
-        exp.run(rounds)
-        jax.block_until_ready(tr.params)
-        counts.append((tr.feed_wait_s, tr.dispatch_s))
-    out = str(tmp_path_factory.mktemp(f"trace-{path}"))
-    jax.profiler.start_trace(out)
-    with jax.profiler.TraceAnnotation("test.window"):
-        exp.run(2)
-        jax.block_until_ready(tr.params)
-    jax.profiler.stop_trace()
+    # At published widths the int8 reduce is a Mosaic kernel, an op of its
+    # own; interpreted here, a leaf that fits one block compiles to a few
+    # elementwise ops the CPU compiler fuses into the server step. The
+    # least block (one sublane tile per grid step) keeps the per-block
+    # loop, as the chip keeps its kernel. Compiled afresh on both sides.
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(fedavg_reduce, "VMEM_BUDGET", 0)
+        jax.clear_caches()
+        exp = build(spec)
+        tr = exp.trainer
+        counts = [(tr.feed_wait_s, tr.dispatch_s)]
+        for rounds in (1, 2):       # the first compiles
+            exp.run(rounds)
+            jax.block_until_ready(tr.params)
+            counts.append((tr.feed_wait_s, tr.dispatch_s))
+        out = str(tmp_path_factory.mktemp(f"trace-{path}"))
+        jax.profiler.start_trace(out)
+        with jax.profiler.TraceAnnotation("test.window"):
+            exp.run(2)
+            jax.block_until_ready(tr.params)
+        jax.profiler.stop_trace()
+    jax.clear_caches()
     xplane = next(os.path.join(d, n) for d, _, fs in os.walk(out)
                   for n in fs if n.endswith(".xplane.pb"))
     texts = {t.splitlines()[0].split()[1].rstrip(","): t for t in
