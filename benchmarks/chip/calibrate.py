@@ -8,13 +8,15 @@ For each of ``--seeds`` seeds the program drives the cell's first steps and
 the reference follows them (sound runs: the lower readings). For the first
 ``--controls`` of them the reference also runs as the control, in
 bfloat16 in the program's place, and with the planted fault of half of
-each batch left out (the upper readings). A step that leaves the state
-unchanged reads 1 on both change gaps by their definition and needs no
-run. One JSON line per reading goes to standard output; the last line
-gives, per number, the lower and upper readings and the limit that
-``checks/<cell>.json`` would take from them: above the lower reading and
-below the upper, at lower**0.35 * upper**0.65, so that more of the room
-lies above the lower reading.
+each batch left out, and the program runs again with each fault that only
+a cell on several chips can have planted (``chipbench.faults.faults_for``:
+the dropped exchange; the upper readings). A step
+that leaves the state unchanged reads 1 on both change gaps by their
+definition and needs no run. One JSON line per reading goes to standard
+output; the last line gives, per number, the lower and upper readings and
+the limit that ``checks/<cell>.json`` would take from them: above the
+lower reading and below the upper, at lower**0.35 * upper**0.65, so that
+more of the room lies above the lower reading.
 """
 from __future__ import annotations
 
@@ -71,9 +73,13 @@ def main(argv=None) -> int:
     if args.rehearse:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
     from chipbench.harness import Run
-    from chipbench import check
+    from chipbench import catalog, check, faults
     sound, uppers = [], {"control": [], "half_batch": [],
                          "unchanged": [UNCHANGED]}
+    chips = catalog.find_cell(args.workload).chips
+    planted = [f for f in faults.faults_for(chips)
+               if f not in faults.faults_for(1)]
+    uppers.update((f, []) for f in planted)
     for i in range(args.seeds):
         seed = args.first_seed + 7919 * i
         run = Run(args.workload, seed, args.rehearse)
@@ -95,7 +101,21 @@ def main(argv=None) -> int:
                 uppers[kind].append(row)
                 print(json.dumps({"seed": seed, "kind": kind, **row}),
                       flush=True)
-        del run
+            sizes = run.sizes
+            del run
+            for kind in planted:
+                with faults.FAULTS[kind]():
+                    bad = Run(args.workload, seed, args.rehearse)
+                    bad.setup()
+                    bad.first_steps()
+                    bad.free_program()
+                row = check.numbers(bad.prog, ref, sizes, bad.unmatched)
+                del bad
+                uppers[kind].append(row)
+                print(json.dumps({"seed": seed, "kind": kind, **row}),
+                      flush=True)
+        else:
+            del run
     uppers["unchanged"] = [dict(sound[0], **UNCHANGED,
                                 loss_gap=float("nan"),
                                 unmatched_rows=float("nan"))]

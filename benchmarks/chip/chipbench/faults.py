@@ -8,11 +8,14 @@ program while a run is built and driven, and restores it after.
 - ``token_altered``: one token of each fed batch is changed where the
   batch is built.
 - ``answer_altered``: the largest parameter leaf moves twice its update.
+- ``exchange_dropped``: on a mesh, the all-reduce that sums the chips'
+  partial int8 reduces (``psum_tiers`` in ``kernels/delta_codec.py``) is
+  left out, so each chip keeps the sum of its own clients alone.
 """
 from __future__ import annotations
 
 import contextlib
-from typing import Iterator
+from typing import Iterator, Tuple
 
 
 @contextlib.contextmanager
@@ -93,5 +96,21 @@ def token_altered() -> Iterator[None]:
         yield
 
 
+@contextlib.contextmanager
+def exchange_dropped() -> Iterator[None]:
+    from repro.kernels import delta_codec
+    with _patched(delta_codec, "psum_tiers",
+                  lambda x, axes, reduce_tiers=None: x):
+        yield
+
+
 FAULTS = {"unchanged": unchanged, "half_batch": half_batch,
-          "token_altered": token_altered, "answer_altered": answer_altered}
+          "token_altered": token_altered, "answer_altered": answer_altered,
+          "exchange_dropped": exchange_dropped}
+
+
+def faults_for(chips: int) -> Tuple[str, ...]:
+    """The faults a cell on ``chips`` chips can have: those of every cell
+    and, across chips, the dropped exchange."""
+    every = ("unchanged", "half_batch", "token_altered", "answer_altered")
+    return every + (("exchange_dropped",) if chips > 1 else ())

@@ -16,6 +16,20 @@ the per-layer metrics are printed instead of the end-to-end ones. Last,
 the program's state is freed and the reference follows the same first
 steps; the numbers of ``check`` decide ``correct``.
 
+Each per-layer metric is a reader ``metrics/<name>.py`` with a
+``read(ctx)``. A reader declares what it reads of the program, and the
+harness hands it over:
+
+- ``KERNELS``: instruction-name prefixes, timed in the trace
+  (``ctx["trace"]["devices"][d]["kernels"]``);
+- ``SCOPES`` and ``SPANS``: device scopes and host spans; the traced
+  stretch is reduced with the reader's own scopes and every declared span
+  (``chipbench.scopes.reduce``, as ``ctx["layers"]``), with the time of
+  the Pallas kernels' calls under each scope;
+- ``COUNTERS``: attributes of the trainer, read just before and just
+  after the window (``ctx["counters"]["program"]``: the deltas, None for
+  an attribute the trainer lacks).
+
 ``--rehearse`` runs the same path on the CPU at the rehearsal sizes of the
 configuration and the mix, with Pallas interpreted, and prints no result.
 """
@@ -31,7 +45,7 @@ import statistics
 import sys
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
@@ -40,6 +54,57 @@ from chipbench import catalog, check, traffic as traffic_mod
 
 class Fail(Exception):
     """A run that must exit non-zero with no result line."""
+
+
+def enable_cache() -> str:
+    """JAX's persistent compile cache (``repro.launch.compile_cache``),
+    with HLO metadata in its key: the program's device scopes live in the
+    ``op_name`` metadata, and an executable cached by a commit without
+    them would come back unscoped."""
+    import jax
+    from repro.launch.compile_cache import enable_compile_cache
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    return enable_compile_cache()
+
+
+DECLARED = ("KERNELS", "SCOPES", "SPANS", "COUNTERS")
+
+
+def declared(readers) -> Dict[str, tuple]:
+    """The union of what ``readers`` (metric reader modules) declare, by
+    kind (``DECLARED``), each name once, in the order first declared."""
+    out: Dict[str, list] = {k: [] for k in DECLARED}
+    for r in readers:
+        for kind in DECLARED:
+            out[kind] += [n for n in getattr(r, kind, ())
+                          if n not in out[kind]]
+    return {k: tuple(v) for k, v in out.items()}
+
+
+def layer_reductions(loaded, texts: List[str], readers,
+                     spans: Sequence[str]) -> Dict[tuple, Optional[dict]]:
+    """``chipbench.scopes.reduce`` of one trace (``scopes.load``) for each
+    distinct ``SCOPES`` the ``readers`` declare, with ``spans``; keyed by
+    those scopes. Each reader's scopes are mapped apart (``scope_map``
+    over the executables' HLO ``texts``), so that a scope one reader
+    declares moves no op of another's."""
+    from chipbench import scopes
+    calls = scopes.pallas_calls(texts)
+    out: Dict[tuple, Optional[dict]] = {}
+    for r in readers:
+        key = tuple(getattr(r, "SCOPES", ()))
+        if key in out or not (key or getattr(r, "SPANS", ())):
+            continue
+        smap = scopes.scope_map(texts, scopes=key) if key else {}
+        out[key] = scopes.reduce(loaded, smap, scopes=key, spans=spans,
+                                 calls=calls)
+    return out
+
+
+def hlo_texts(exp) -> List[str]:
+    """The optimised HLO of every executable the round engine holds."""
+    return [e.as_text()
+            for e in exp.trainer.engine.registry.executables()]
 
 
 def parse(argv):
@@ -316,8 +381,10 @@ class Run:
         import jax
         self.jax = jax
         if not rehearse:
-            from repro.launch.compile_cache import enable_compile_cache
-            enable_compile_cache()
+            enable_cache()
+        self.readers = {m["name"]: self.cell.metric_reader(m["name"])
+                        for m in self.cell.per_layer}
+        self.wants = declared(self.readers.values())
         self.compiles = compile_counter()
         self.devs, self.peaks = device_info(rehearse, self.cell.chips)
         self.g = traffic_mod.geometry(self.cell.traffic, rehearse)
@@ -398,11 +465,13 @@ class Run:
         inst.wait_s = 0.0
         inst.clock = CommitClock()
         n_exec, n_comp = exp.trainer.compile_count, self.compiles["n"]
+        p0 = self._program_counters()
         t0 = time.perf_counter()
         self.setup_s = t0 - t_start
         h = exp.run(rounds)
         jax.block_until_ready(exp.trainer.params)
         t1 = time.perf_counter()
+        p1 = self._program_counters()
         inst.clock.close()
         commit, inst.clock = inst.clock.times, None
         if exp.trainer.compile_count != n_exec or \
@@ -425,13 +494,22 @@ class Run:
             "pipeline_wait_ms": 1e3 * inst.wait_s / rounds,
             "peak_bytes": peak, "chips": self.cell.chips,
             "leaf_sizes": [int(x) for x in self.sizes],
+            "program": {k: None if p0[k] is None or p1[k] is None
+                        else p1[k] - p0[k] for k in p0},
         }
+
+    def _program_counters(self) -> Dict[str, Any]:
+        tr = self.exp.trainer
+        return {k: getattr(tr, k, None) for k in self.wants["COUNTERS"]}
 
     def traced(self, seconds: float, out_dir: Optional[str] = None):
         """Profile a short stretch of rounds after the window and reduce
-        the trace (None in a rehearsal: the CPU has no device plane)."""
+        the trace: the device numbers (``trace``, with the declared
+        kernels) and the program's layers (``layers``, by the readers'
+        scopes and spans). Both read None where the trace holds no device
+        plane, as on the CPU."""
         jax, exp = self.jax, self.exp
-        from chipbench import trace as trace_mod
+        from chipbench import scopes, trace as trace_mod
         n_tr = self.per_step * max(1, math.ceil(
             min(3.0, seconds) / self.round_s / self.per_step))
         out_dir = out_dir or str(catalog.REPO / ".chipbench" / "trace"
@@ -443,8 +521,12 @@ class Run:
         jax.profiler.stop_trace()
         self.counters["traced_rounds"] = n_tr
         try:
-            self.trace = (None if self.rehearse else
-                          trace_mod.reduce(trace_mod.load(_xplane(out_dir))))
+            path = _xplane(out_dir)
+            self.trace = trace_mod.reduce(trace_mod.load(path),
+                                          kernels=self.wants["KERNELS"])
+            self.layers = layer_reductions(
+                scopes.load(path), hlo_texts(exp), self.readers.values(),
+                self.wants["SPANS"])
         finally:
             _rmtree(out_dir)
 
@@ -499,7 +581,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
     run.setup()
     run.first_steps()
     run.window(seconds, t_start)
-    run.trace = None
+    run.trace, run.layers = None, {}
     if trace:
         run.traced(seconds, trace_dir)
     run.free_program()
@@ -509,7 +591,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
                   for k in values}
     return {"cell": run.cell, "counters": run.counters,
             "setup_s": run.setup_s, "losses": run.losses,
-            "trace": run.trace, "checks": check.judge(values, limits),
+            "trace": run.trace, "layers": run.layers,
+            "readers": run.readers, "checks": check.judge(values, limits),
             "peaks": run.peaks, "model": run.m, "geometry": run.g,
             "devices": run.devs[:run.cell.chips], "ref": run.ref}
 
@@ -557,7 +640,10 @@ def result_line(res, trace: bool) -> Dict[str, Any]:
     else:
         ctx = metric_context(res)
         for mdef in cell.per_layer:
-            v = cell.metric_reader(mdef["name"]).read(ctx)
+            reader = res["readers"][mdef["name"]]
+            ctx["layers"] = res["layers"].get(
+                tuple(getattr(reader, "SCOPES", ())))
+            v = reader.read(ctx)
             if v is not None:
                 metrics[mdef["name"]] = {"value": v, "unit": mdef["unit"]}
     devs = res["devices"]

@@ -11,17 +11,23 @@ op is keyed by ``(module, instruction)``: the module from the event's
 ``XLA Modules`` line. ``scope_map`` reads each instruction's scope from the
 HLO text of the executables (``compiled.as_text()``).
 
-The names are kept here, not imported from the program, so that the
-yardstick stays put when the program changes; a test holds the two lists
-equal. Everything below, but ``load``, works on plain tuples so that it
-can be checked on a trace made up by hand.
+The names are kept on the benchmark's side, not imported from the
+program, so that the yardstick stays put when the program changes: the
+readers under ``metrics/`` declare the scopes and spans they read, and the
+harness reduces the trace with each reader's own scopes, so that a name
+declared later changes no existing reading. ``SCOPES`` and ``SPANS`` are
+the names of the round engine's layers, read by ``layer_metrics``.
+Everything below, but ``load``, works on plain tuples so that it can be
+checked on a trace made up by hand.
 """
 from __future__ import annotations
 
 import bisect
+import itertools
 import re
-from typing import Dict, Iterable, List, NamedTuple, Optional, Sequence, \
-    Tuple
+import statistics
+from typing import AbstractSet, Dict, Iterable, List, NamedTuple, \
+    Optional, Sequence, Tuple
 
 from chipbench import trace
 
@@ -61,8 +67,10 @@ def module_name(event_name: str) -> str:
 def load(path: str) -> Loaded:
     """Every op event of one ``.xplane.pb`` with its module, the module
     executions of each device, and the host lines. A device plane's ops
-    come from its ``XLA Ops`` line; elsewhere (the CPU) an op is any event
-    with ``hlo_op`` and ``hlo_module`` stats."""
+    come from its ``XLA Ops`` lines and its modules from its ``XLA
+    Modules`` lines, every line of the name (the profiler can split one
+    over several); elsewhere (the CPU) an op is any event with ``hlo_op``
+    and ``hlo_module`` stats."""
     from jax.profiler import ProfileData
     ops: Dict[str, List[Op]] = {}
     modules: Dict[str, List[trace.Event]] = {}
@@ -71,17 +79,18 @@ def load(path: str) -> Loaded:
     for plane in ProfileData.from_file(path).planes:
         device = trace.DEVICE_PLANE.match(plane.name) is not None
         lines = list(plane.lines)
-        mods = []
-        for line in lines:
-            if device and line.name == "XLA Modules":
-                mods = sorted(((module_name(e.name), float(e.start_ns),
-                                float(e.end_ns)) for e in line.events),
-                              key=lambda m: m[1])
+        mods = sorted(((module_name(e.name), float(e.start_ns),
+                        float(e.end_ns))
+                       for line in lines if device
+                       and line.name == "XLA Modules"
+                       for e in line.events), key=lambda m: m[1])
         if device:
             modules[plane.name] = mods
         starts = [m[1] for m in mods]
+        reach = list(itertools.accumulate((m[2] for m in mods), max))
         for i, line in enumerate(lines):
-            key = f"{plane.name}/{line.name}#{i}"
+            key = (f"{plane.name}/{line.name}" if device
+                   else f"{plane.name}/{line.name}#{i}")
             events = []
             for e in line.events:
                 st = dict(e.stats)
@@ -92,7 +101,7 @@ def load(path: str) -> Loaded:
                     op = str(st.get("hlo_op") or trace.op_name(e.name))
                     mod = st.get("hlo_module")
                     mod = (module_name(str(mod)) if mod
-                           else _enclosing(mods, starts, s))
+                           else _enclosing(mods, starts, reach, s))
                 elif "hlo_op" in st and "hlo_module" in st:
                     op = str(st["hlo_op"])
                     mod = module_name(str(st["hlo_module"]))
@@ -108,11 +117,16 @@ def load(path: str) -> Loaded:
 
 
 def _enclosing(mods: Sequence[trace.Event], starts: Sequence[float],
-               t: float) -> str:
-    """The module execution (``mods`` sorted by their ``starts``) that
-    holds time ``t``."""
+               reach: Sequence[float], t: float) -> str:
+    """The module execution that holds time ``t``, the latest to start
+    where several overlap (``mods`` sorted by their ``starts``; ``reach``
+    the latest end of each prefix of them)."""
     k = bisect.bisect_right(starts, t) - 1
-    return mods[k][0] if k >= 0 and t < mods[k][2] else ""
+    while k >= 0 and reach[k] > t:
+        if t < mods[k][2]:
+            return mods[k][0]
+        k -= 1
+    return ""
 
 
 def window_line(host: Dict[str, List[trace.Event]], name: str
@@ -139,6 +153,7 @@ _CALLED = re.compile(r"\b(?:body|condition|true_computation|"
                      r"false_computation)=%?([\w.\-]+)")
 _BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
 _TOKEN = re.compile(r"[A-Za-z_][\w.\-]*")
+_PALLAS = 'custom_call_target="tpu_custom_call"'
 
 
 def scope_of(path: str, scopes: Sequence[str] = SCOPES) -> Optional[str]:
@@ -158,6 +173,7 @@ class _Instr(NamedTuple):
     fused: str          # the computation a fusion calls, or ""
     called: List[str]   # loop and branch bodies it runs
     refs: List[str]     # operands (and called computations)
+    pallas: bool        # a Pallas kernel's call (``tpu_custom_call``)
 
 
 def _parse(text: str):
@@ -187,7 +203,8 @@ def _parse(text: str):
                        if x.strip()]
         instrs.append(_Instr(name, comp, p.group(1) if p else "",
                              f.group(1) if f else "", called,
-                             _REF.findall(rhs.split(", metadata=", 1)[0])))
+                             _REF.findall(rhs.split(", metadata=", 1)[0]),
+                             _PALLAS in rhs))
         if root:
             roots[comp] = name
     return module, instrs, roots
@@ -238,13 +255,25 @@ def scope_map(texts: Iterable[str], scopes: Sequence[str] = SCOPES
     return out
 
 
+def pallas_calls(texts: Iterable[str]) -> AbstractSet[Tuple[str, str]]:
+    """``{(module, instruction)}`` of the Pallas kernels' calls
+    (``tpu_custom_call``) in the given optimised HLO modules, whatever
+    the compiler named the instruction."""
+    out = set()
+    for text in texts:
+        module, instrs, _ = _parse(text)
+        out.update((module, i.name) for i in instrs if i.pallas)
+    return frozenset(out)
+
+
 def resolve(op: Op, smap: Dict[Tuple[str, str], str],
-            tf_ops: Dict[Tuple[str, str], str]) -> str:
+            tf_ops: Dict[Tuple[str, str], str],
+            scopes: Sequence[str] = SCOPES) -> str:
     """The scope of one executed op: the path the trace carries on the
     event where it has one, else the HLO's; ``UNSCOPED`` where neither
     places it."""
     key = (op.module, op.op)
-    s = scope_of(tf_ops.get(key, ""))
+    s = scope_of(tf_ops.get(key, ""), scopes)
     return s or smap.get(key) or UNSCOPED
 
 
@@ -252,8 +281,8 @@ def resolve(op: Op, smap: Dict[Tuple[str, str], str],
 # device time per scope, idle time per span
 # ---------------------------------------------------------------------------
 
-def scope_times(ops: Sequence[Op], smap, tf_ops, lo: float, hi: float
-                ) -> Dict[str, float]:
+def scope_times(ops: Sequence[Op], smap, tf_ops, lo: float, hi: float,
+                scopes: Sequence[str] = SCOPES) -> Dict[str, float]:
     """ns of device time per scope inside [lo, hi]: each op's self time
     (``trace.self_times``: a loop's body ops, not the loop, hold their
     time) goes to the op's scope."""
@@ -262,7 +291,7 @@ def scope_times(ops: Sequence[Op], smap, tf_ops, lo: float, hi: float
     out: Dict[str, float] = {}
     for key, ns in trace.self_times(ev).items():
         module, op = key.split("/", 1)
-        s = resolve(Op(module, op, 0.0, 0.0), smap, tf_ops)
+        s = resolve(Op(module, op, 0.0, 0.0), smap, tf_ops, scopes)
         out[s] = out.get(s, 0.0) + ns
     return out
 
@@ -313,10 +342,35 @@ def launch_leads(modules: Sequence[trace.Event], host: Sequence[trace.Event],
     return out
 
 
+def pallas_times(ops: Sequence[Op], smap, tf_ops, calls, lo: float,
+                 hi: float, scopes: Sequence[str]) -> Dict[str, list]:
+    """Per scope, [ns inside [lo, hi], count] of the events of the Pallas
+    kernels' calls (``calls``, from ``pallas_calls``) that lie in it."""
+    out = {s: [0.0, 0] for s in scopes}
+    for o in ops:
+        if (o.module, o.op) not in calls:
+            continue
+        s = resolve(o, smap, tf_ops, scopes)
+        c = trace.clip([(o.start, o.end)], lo, hi)
+        if s in out and c:
+            out[s][0] += trace.length(c)
+            out[s][1] += 1
+    return out
+
+
 def reduce(loaded: Loaded, smap: Dict[Tuple[str, str], str],
-           window_span: str = "bench.window") -> Optional[dict]:
-    """Per device plane: busy ns, device ns per scope and idle ns per span
-    inside the window; None where the trace holds no device op or no
+           window_span: str = "bench.window",
+           scopes: Sequence[str] = SCOPES,
+           spans: Sequence[str] = SPANS,
+           calls: AbstractSet[Tuple[str, str]] = frozenset()
+           ) -> Optional[dict]:
+    """Per device plane, inside the window: busy ns; device ns per scope
+    (``smap`` made with the same ``scopes``; each declared scope is there,
+    0 where no op falls in it) and, of it, [ns, count] of the Pallas
+    kernels' calls (``pallas_ns``; ``calls`` from ``pallas_calls``); idle
+    ns under each span (``span_idle_ns``: all of the idle time the span
+    covers, whatever lies inside it) and idle ns per innermost span
+    (``idle_by_span_ns``). None where the trace holds no device op or no
     window."""
     found = window_line(loaded.host, window_span)
     devs = {k: v for k, v in loaded.ops.items() if k.startswith("/device")}
@@ -324,20 +378,67 @@ def reduce(loaded: Loaded, smap: Dict[Tuple[str, str], str],
         return None
     (lo, hi), host = found
     out = {"window_ns": hi - lo, "devices": {}}
-    dispatch = span_intervals(host, "round.dispatch", lo, hi)
+    under = {s: span_intervals(host, s, lo, hi) for s in spans}
     for key, ops in sorted(devs.items()):
         busy = trace.busy([(o.op, o.start, o.end) for o in ops], lo, hi)
         idle = trace.idle_gaps(busy, lo, hi)
         plane = key.split("/XLA Ops", 1)[0]
+        scoped = dict.fromkeys(scopes, 0.0)
+        if scopes:
+            scoped.update(scope_times(ops, smap, loaded.tf_ops, lo, hi,
+                                      scopes))
         out["devices"][plane] = {
             "busy_ns": trace.length(busy),
-            "scopes_ns": scope_times(ops, smap, loaded.tf_ops, lo, hi),
-            "idle_by_span_ns": idle_by_span(idle, host),
-            "dispatch_idle_ns": trace.length(trace.intersect(idle,
-                                                             dispatch)),
+            "scopes_ns": scoped,
+            "pallas_ns": pallas_times(ops, smap, loaded.tf_ops, calls, lo,
+                                      hi, scopes),
+            "idle_by_span_ns": idle_by_span(idle, host, spans),
+            "span_idle_ns": {s: trace.length(trace.intersect(idle, iv))
+                             for s, iv in under.items()},
             "launch_leads_ns": launch_leads(
                 loaded.modules.get(plane, []), host,
                 {"jit_slab": "slab.call", "jit_slabfin": "finalize.call",
                  "jit_bucket": "bucket.call"}, lo, hi),
         }
     return out
+
+
+# ---------------------------------------------------------------------------
+# the round engine's layers, per round
+# ---------------------------------------------------------------------------
+
+def layer_metrics(devs: Optional[Sequence[dict]], rounds_traced: int
+                  ) -> dict:
+    """The device's layer numbers, ms per traced round: device time under
+    each of ``SCOPES`` (``<scope>_ms``) and device idle time under the
+    ``round.dispatch`` span (``dispatch.idle_ms``), each the mean over
+    ``devs`` (the planes of one ``reduce``; None without a trace). A
+    number whose scope or span the reduction lacks is left out."""
+    out = {}
+    if devs:
+        per = 1e6 * rounds_traced
+        for s in SCOPES:
+            if all(s in d["scopes_ns"] for d in devs):
+                out[f"{s}_ms"] = statistics.fmean(
+                    d["scopes_ns"][s] for d in devs) / per
+        if all("round.dispatch" in d["span_idle_ns"] for d in devs):
+            out["dispatch.idle_ms"] = statistics.fmean(
+                d["span_idle_ns"]["round.dispatch"] for d in devs) / per
+    return out
+
+
+def layer_reading(ctx: dict, name: str) -> Optional[float]:
+    """One number of ``layer_metrics`` from a metric reader's context (its
+    reduction, ``ctx["layers"]``)."""
+    red = ctx.get("layers")
+    devs = None if red is None else list(red["devices"].values())
+    return layer_metrics(devs, ctx["counters"].get("traced_rounds", 0)
+                         ).get(name)
+
+
+def counter_ms(counters: dict, name: str) -> Optional[float]:
+    """The trainer's counter ``name`` over the window (its delta in
+    ``counters["program"]``, in seconds) in ms per window round; None
+    where the program has no such counter."""
+    v = (counters.get("program") or {}).get(name)
+    return None if v is None else 1e3 * v / counters["rounds"]

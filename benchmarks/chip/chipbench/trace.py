@@ -27,15 +27,21 @@ COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
 
 
 def load(path: str) -> Dict[str, Dict[str, List[Event]]]:
-    """``{plane: {line: [(name, start_ns, end_ns), ...]}}`` of one trace."""
+    """``{plane: {line: [(name, start_ns, end_ns), ...]}}`` of one trace. A
+    device plane's lines of one name are read as one (the profiler can
+    split a line, such as ``XLA Ops``, over several); a host plane's
+    threads of one name stay apart (``<name>#<index>``)."""
     from jax.profiler import ProfileData
     out: Dict[str, Dict[str, List[Event]]] = {}
     for plane in ProfileData.from_file(path).planes:
+        device = plane.name.startswith("/device")
         lines: Dict[str, List[Event]] = {}
         for i, line in enumerate(plane.lines):
-            key = line.name if line.name not in lines else f"{line.name}#{i}"
-            lines[key] = [(e.name, float(e.start_ns), float(e.end_ns))
-                          for e in line.events]
+            key = (line.name if device or line.name not in lines
+                   else f"{line.name}#{i}")
+            lines.setdefault(key, []).extend(
+                (e.name, float(e.start_ns), float(e.end_ns))
+                for e in line.events)
         out[plane.name] = lines
     return out
 

@@ -4,21 +4,16 @@
         --seconds <s> [--out <file.json>]
     python3 benchmarks/chip/layers.py --workload <cell> --rehearse   # CPU
 
-Set-up and the timed window are the benchmark's own (``harness.Run``).
-Around the window it reads the trainer's host counters (``feed_wait_s``,
-``dispatch_s``); after it, it runs the traced stretch of ``run.py
---trace 1`` twice, once untraced and once under the profiler, and reduces
-the trace by the program's device scopes and host spans
-(``chipbench.scopes``), mapping each op to its scope through the HLO of
-the engine's executables. It prints one JSON object (and writes it to
-``--out``): the per-round layer times, the checks of the reduction (scope
+Set-up, the timed window and the trainer's counters over it are the
+benchmark's own (``harness.Run``). After the window it runs the traced
+stretch of ``run.py --trace 1`` twice, once untraced and once under the
+profiler, and reduces the trace by the program's device scopes and host
+spans (``chipbench.scopes``), mapping each op to its scope through the HLO
+of the engine's executables. It prints one JSON object (and writes it to
+``--out``): the per-round layer times (``scopes.layer_metrics``, as the
+benchmark's readers give them), the checks of the reduction (scope
 coverage of busy time, idle parts against idle time, host call spans
 against the device's module starts) and the profiler's cost.
-
-The device scopes live in HLO metadata, which JAX's persistent compile
-cache leaves out of its key: run with
-``JAX_COMPILATION_CACHE_INCLUDE_METADATA_IN_KEY=true`` (or a fresh cache),
-or executables cached before the scopes existed come back without them.
 """
 import time
 
@@ -37,34 +32,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(HERE)),
 
 from chipbench import catalog, harness, scopes, trace  # noqa: E402
 from chipbench import traffic as traffic_mod  # noqa: E402
-
-KERNEL = "int8_decompress_reduce"
-
-
-def layer_metrics(dev, rounds_traced: int, window_rounds: int,
-                  host_s) -> dict:
-    """The per-round layer numbers (ms per round) from one device's
-    reduction (``scopes.reduce(...)["devices"][plane]``, None without a
-    trace) and the trainer's counter deltas over the window (``host_s``:
-    ``{"feed_wait_s": .., "dispatch_s": ..}``, None where the program has
-    no such counter)."""
-    out = {}
-    if dev is not None:
-        per = 1e6 * rounds_traced
-        for s in scopes.SCOPES:
-            out[f"{s}_ms"] = dev["scopes_ns"].get(s, 0.0) / per
-        out["dispatch.idle_ms"] = dev["dispatch_idle_ns"] / per
-    for name, key in (("dispatch.host_ms", "dispatch_s"),
-                      ("feed.wait_ms", "feed_wait_s")):
-        v = (host_s or {}).get(key)
-        if v is not None:
-            out[name] = 1e3 * v / window_rounds
-    return out
-
-
-def counters(trainer):
-    return {k: getattr(trainer, k, None)
-            for k in ("feed_wait_s", "dispatch_s")}
 
 
 def timed_rounds(run, n: int) -> float:
@@ -95,12 +62,12 @@ def measure(name: str, seed: int, seconds: float, rehearse: bool,
     run = harness.Run(name, seed, rehearse)
     run.setup()
     run.first_steps()
-    tr = run.exp.trainer
-    c0 = counters(tr)
     run.window(seconds, T_START)
-    c1 = counters(tr)
-    host_s = {k: (None if c0[k] is None else c1[k] - c0[k]) for k in c0}
     c = run.counters
+    # the counter readers' numbers, as the benchmark prints them
+    counted = {name: r.read({"counters": c})
+               for name, r in run.readers.items()
+               if getattr(r, "COUNTERS", ())}
     n_tr = run.per_step * max(1, math.ceil(
         min(3.0, seconds) / run.round_s / run.per_step))
     per_round = traffic_mod.samples_per_round(run.g)
@@ -109,7 +76,7 @@ def measure(name: str, seed: int, seconds: float, rehearse: bool,
     with run.inst.span("bench.window"):
         traced_s = timed_rounds(run, n_tr)
     run.jax.profiler.stop_trace()
-    texts = [e.as_text() for e in tr.engine.registry.executables()]
+    texts = harness.hlo_texts(run.exp)
     smap = scopes.scope_map(texts)
     res = {
         "cell": name, "seed": seed,
@@ -128,26 +95,23 @@ def measure(name: str, seed: int, seconds: float, rehearse: bool,
     }
     path = harness._xplane(out_dir)
     loaded = scopes.load(path)
-    red = scopes.reduce(loaded, smap)
-    res["layers"] = layer_metrics(None, n_tr, c["rounds"], host_s)
+    red = scopes.reduce(loaded, smap, calls=scopes.pallas_calls(texts))
+    res["layers"] = counted
     if red is None:
         return res
-    (lo, hi), _ = scopes.window_line(loaded.host, "bench.window")
     res["sample"] = sample(path)
     res["devices"] = {}
     for plane, dev in red["devices"].items():
-        ops = next(v for k, v in loaded.ops.items() if k.startswith(plane))
         busy = dev["busy_ns"]
         scoped = sum(dev["scopes_ns"].get(s, 0.0) for s in scopes.SCOPES)
         idle = red["window_ns"] - busy
-        k_ns, k_calls = trace.kernel_time(
-            [(o.op, o.start, o.end) for o in ops], KERNEL, lo, hi)
+        k_ns, k_calls = dev["pallas_ns"]["uplink.reduce"]
         leads = {m: {"counts": v["counts"],
                      "min_ms": min(v["leads_ns"], default=0.0) / 1e6,
                      "negative": sum(x < 0 for x in v["leads_ns"])}
                  for m, v in dev["launch_leads_ns"].items()}
         res["devices"][plane] = {
-            "layers": layer_metrics(dev, n_tr, c["rounds"], host_s),
+            "layers": {**scopes.layer_metrics([dev], n_tr), **counted},
             "idle_share": 100.0 * idle / red["window_ns"],
             "busy_ms_per_round": busy / 1e6 / n_tr,
             "scoped_share_of_busy": 100.0 * scoped / busy,
@@ -165,7 +129,8 @@ def measure(name: str, seed: int, seconds: float, rehearse: bool,
             "int8_reduce_calls": k_calls,
             "launch_leads": leads,
         }
-    res["layers"] = res["devices"][min(res["devices"])]["layers"]
+    res["layers"] = {**scopes.layer_metrics(list(red["devices"].values()),
+                                            n_tr), **counted}
     return res
 
 

@@ -1,6 +1,7 @@
 """The reduction from a profiler trace to the program's layers
-(``chipbench.scopes``) and the per-round numbers ``layers.py`` prints, on
-HLO and traces made up by hand and on one recorded on the CPU."""
+(``chipbench.scopes``) and the per-round numbers that the layer readers and
+``layers.py`` give, on HLO and traces made up by hand and on one recorded
+on the CPU."""
 import os
 import sys
 
@@ -8,7 +9,7 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from chipbench import catalog, scopes  # noqa: E402
+from chipbench import catalog, harness, scopes  # noqa: E402
 from chipbench.scopes import Op  # noqa: E402
 
 ROOT = catalog.ROOT
@@ -96,9 +97,18 @@ def test_ops_without_metadata_take_a_users_or_their_loops_scope(smap):
 def test_an_op_takes_the_module_run_that_holds_it():
     # a device op event without an hlo_module stat: the XLA Modules line
     runs = [("jit_slab", 0.0, 10.0), ("jit_slabfin", 12.0, 20.0)]
-    starts = [0.0, 12.0]
-    got = [scopes._enclosing(runs, starts, t) for t in (0, 9.9, 11, 12, 25)]
+    starts, reach = [0.0, 12.0], [10.0, 20.0]
+    got = [scopes._enclosing(runs, starts, reach, t)
+           for t in (0, 9.9, 11, 12, 25)]
     assert got == ["jit_slab", "jit_slab", "", "jit_slabfin", ""]
+    # runs that overlap: the latest to start that holds the time
+    runs = [("jit_slab", 0.0, 60.0), ("jit_put", 25.0, 26.0),
+            ("jit_put", 30.0, 31.0)]
+    starts, reach = [0.0, 25.0, 30.0], [60.0, 60.0, 60.0]
+    got = [scopes._enclosing(runs, starts, reach, t)
+           for t in (20, 25.5, 28, 30.5, 50, 61)]
+    assert got == ["jit_slab", "jit_put", "jit_slab", "jit_put", "jit_slab",
+                   ""]
     assert scopes.module_name("jit_slab(1234)") == "jit_slab"
 
 
@@ -157,7 +167,9 @@ def test_reduce_splits_a_window_by_scope_and_span(smap):
     assert d["scopes_ns"] == {"client.step": 20.0, "uplink.reduce": 10.0,
                               "uplink.encode": 10.0, "server.step": 10.0}
     # idle: 0-20, 60-70, 80-100; round.dispatch covers 5-20 and 60-70
-    assert d["dispatch_idle_ns"] == 15 + 10
+    assert d["span_idle_ns"] == {s: 0.0 for s in scopes.SPANS} | {
+        "round.dispatch": 15 + 10, "feed.wait": 10, "slab.call": 3,
+        "finalize.call": 1, "round.absorb": 10, "loss.sync": 10}
     assert d["idle_by_span_ns"] == {scopes.OUTSIDE: 5 + 5 + 5,
                                     "feed.wait": 10, "slab.call": 3,
                                     "round.dispatch": 2 + 10 - 1,
@@ -167,6 +179,45 @@ def test_reduce_splits_a_window_by_scope_and_span(smap):
         "jit_slabfin": {"counts": [1, 1], "leads_ns": [5.0]}}
 
 
+#: a mesh slab: the int8 reduce's Pallas call inside ``shard_map`` is named
+#: after it, not after the kernel's jit
+MESH_SLAB = """HloModule jit_slab, is_scheduled=true
+
+ENTRY %main.4 (Arg_0.1: s8[1,8], Arg_1.2: f32[1]) -> f32[8] {
+  %Arg_0.1 = s8[1,8]{1,0} parameter(0)
+  %Arg_1.2 = f32[1]{0} parameter(1)
+  %shard_map.19 = f32[8]{0} custom-call(%Arg_1.2, %Arg_0.1), custom_call_target="tpu_custom_call", metadata={op_name="jit(slab)/uplink.encode/uplink.reduce/shard_map/pallas_call"}, backend_config={"custom_call_config":{"body":"AAA="}}
+  %psum.7 = f32[8]{0} all-reduce(%shard_map.19), replica_groups={{0,1,2,3}}, to_apply=%region_0.0, metadata={op_name="jit(slab)/uplink.encode/uplink.reduce/shard_map/psum"}
+  ROOT %custom-call.2 = f32[8]{0} custom-call(%psum.7), custom_call_target="Sharding", metadata={op_name="jit(slab)/uplink.encode/sharding"}
+}
+"""
+
+
+def test_pallas_calls_are_found_by_target_whatever_their_name():
+    texts = [MESH_SLAB, SLABFIN]
+    assert scopes.pallas_calls(texts) == {("jit_slab", "shard_map.19")}
+    ops = [Op("jit_slab", "shard_map.19", 10, 14),
+           Op("jit_slab", "psum.7", 14, 20),
+           Op("jit_slab", "shard_map.19", 30, 34),
+           Op("jit_slab", "shard_map.19", 95, 105)]   # runs past the window
+    dev = "/device:TPU:1"
+    lo = scopes.Loaded({f"{dev}/XLA Ops#0": ops},
+                       {dev: [("jit_slab", 10, 105)]},
+                       {"/host:CPU/python#0": [("bench.window", 0, 100)]},
+                       {})
+    for declared in (scopes.SCOPES, ("uplink.reduce",)):
+        red = scopes.reduce(lo, scopes.scope_map(texts, declared),
+                            scopes=declared, spans=(),
+                            calls=scopes.pallas_calls(texts))
+        got = red["devices"][dev]["pallas_ns"]
+        assert got["uplink.reduce"] == [4.0 + 4.0 + 5.0, 3]
+        assert all(v == [0.0, 0] for k, v in got.items()
+                   if k != "uplink.reduce")
+    # without the HLO's calls no op is a kernel's
+    red = scopes.reduce(lo, scopes.scope_map(texts), spans=())
+    assert red["devices"][dev]["pallas_ns"]["uplink.reduce"] == [0.0, 0]
+
+
 def test_reduce_reads_nothing_without_a_device_or_a_window(smap):
     lo = _loaded()
     assert scopes.reduce(lo._replace(ops={}), smap) is None
@@ -174,21 +225,93 @@ def test_reduce_reads_nothing_without_a_device_or_a_window(smap):
 
 
 def test_layer_numbers_per_round():
-    layers = catalog.load_module(ROOT / "layers.py", "chipbench_layers")
-    dev = {"scopes_ns": {"client.step": 4e6, "uplink.reduce": 2e6},
-           "dispatch_idle_ns": 1e6}
-    host = {"feed_wait_s": 0.002, "dispatch_s": 0.010}
-    got = layers.layer_metrics(dev, 2, 5, host)
+    dev = {"scopes_ns": {"client.step": 4e6, "uplink.encode": 0.0,
+                         "uplink.reduce": 2e6, "server.step": 0.0},
+           "span_idle_ns": {"round.dispatch": 1e6}}
+    got = scopes.layer_metrics([dev], 2)
     assert got == pytest.approx({
         "client.step_ms": 2.0, "uplink.encode_ms": 0.0,
         "uplink.reduce_ms": 1.0, "server.step_ms": 0.0,
-        "dispatch.idle_ms": 0.5, "dispatch.host_ms": 2.0,
-        "feed.wait_ms": 0.4})
-    # no trace: the host counters alone; a program without them: nothing
-    assert set(layers.layer_metrics(None, 2, 5, host)) == {
-        "dispatch.host_ms", "feed.wait_ms"}
-    assert layers.layer_metrics(
-        None, 2, 5, {"feed_wait_s": None, "dispatch_s": None}) == {}
+        "dispatch.idle_ms": 0.5})
+    # the mean over the chips, as every device reading; a scope or span
+    # the reduction lacks is left out, not read as 0
+    other = {"scopes_ns": {"client.step": 8e6}, "span_idle_ns": {}}
+    assert scopes.layer_metrics([dev, other], 2) == \
+        pytest.approx({"client.step_ms": 3.0})
+    assert scopes.layer_metrics(None, 2) == {}
+    # the counters per window round; a program without one: nothing
+    counters = {"rounds": 5, "program": {"feed_wait_s": 0.002,
+                                         "dispatch_s": None}}
+    assert scopes.counter_ms(counters, "feed_wait_s") == pytest.approx(0.4)
+    assert scopes.counter_ms(counters, "dispatch_s") is None
+    assert scopes.counter_ms(counters, "no_such_count") is None
+    # layers.py prints the same functions' numbers
+    layers = catalog.load_module(ROOT / "layers.py", "chipbench_layers")
+    assert layers.scopes.layer_metrics is scopes.layer_metrics
+
+
+LAYER_METRICS = ("client.step_ms", "uplink.encode_ms", "uplink.reduce_ms",
+                 "server.step_ms", "dispatch.idle_ms", "dispatch.host_ms",
+                 "feed.wait_ms")
+
+#: the slab of ``SLAB`` with a scope nested in the client step, as a
+#: program that names its experts would write it
+NESTED = SLAB.replace('"jit(slab)/vmap(client.step)/while"',
+                      '"jit(slab)/vmap(client.step)/moe.experts/while"')
+
+
+def reader(name):
+    return catalog.load_module(ROOT / "metrics" / f"{name}.py",
+                               f"chipbench_metric_{name}")
+
+
+def readings(readers, texts, counters):
+    """Each reader's value as the harness hands it its reduction."""
+    spans = harness.declared(readers.values())["SPANS"]
+    red = harness.layer_reductions(_loaded(), texts, readers.values(), spans)
+    out = {}
+    for name, r in readers.items():
+        ctx = {"counters": counters,
+               "layers": red.get(tuple(getattr(r, "SCOPES", ())))}
+        out[name] = r.read(ctx)
+    return out
+
+
+COUNTERS = {"rounds": 5, "traced_rounds": 2,
+            "program": {"dispatch_s": 0.010, "feed_wait_s": 0.002}}
+
+
+@pytest.mark.parametrize("name", LAYER_METRICS)
+def test_each_layer_reader_equals_layer_metrics(name):
+    r = reader(name)
+    red = scopes.reduce(_loaded(), scopes.scope_map([SLAB, SLABFIN]))
+    want = scopes.layer_metrics(list(red["devices"].values()), 2)
+    want.update({"dispatch.host_ms": scopes.counter_ms(COUNTERS,
+                                                       "dispatch_s"),
+                 "feed.wait_ms": scopes.counter_ms(COUNTERS,
+                                                   "feed_wait_s")})
+    got = readings({name: r}, [SLAB, SLABFIN], COUNTERS)[name]
+    assert got == want[name]
+    assert r.read({"counters": {"rounds": 5}, "layers": None}) is None
+
+
+@pytest.mark.parametrize("extra", [("client.step", "moe.experts"),
+                                   ("moe.experts",)])
+def test_a_scope_declared_later_moves_no_layer_reading(extra):
+    # a later reader declares a scope the program nests in client.step;
+    # the seven readings stay as they were, and it reads its own scope
+    seven = {n: reader(n) for n in LAYER_METRICS}
+    before = readings(seven, [NESTED, SLABFIN], COUNTERS)
+    new = catalog.load_module(ROOT / "metrics" / "client.step_ms.py", "new")
+    new.SCOPES, new.SPANS = extra, ("loss.sync",)
+    new.read = lambda ctx: ctx["layers"]["devices"]["/device:TPU:0"][
+        "scopes_ns"]["moe.experts"]
+    after = readings(dict(seven, new=new), [NESTED, SLABFIN], COUNTERS)
+    # by its own scopes the loop and what it feeds are the experts': the
+    # loop's self time, the fusion in its body and the fusion after it
+    assert after.pop("new") == 20.0 + 10.0 + 10.0
+    assert after == before
+    assert before["client.step_ms"] == pytest.approx(20.0 / 2e6)
 
 
 def test_load_keeps_the_module_of_each_op(tmp_path):
@@ -215,3 +338,60 @@ def test_load_keeps_the_module_of_each_op(tmp_path):
     mods = {o.module for line in got.ops.values() for o in line}
     assert {"jit_first", "jit_second"} <= mods
     assert scopes.window_line(got.host, "bench.window") is not None
+
+
+#: a device plane whose ``XLA Modules`` and ``XLA Ops`` are each split over
+#: two lines, with a short program that overlaps the slab
+SPLIT_PLANES = """
+planes {
+  id: 1 name: "/device:TPU:0"
+  lines { id: 1 name: "XLA Modules" timestamp_ns: 0
+          events { metadata_id: 1 offset_ps: 0 duration_ps: 60000 } }
+  lines { id: 2 name: "XLA Ops" timestamp_ns: 0
+          events { metadata_id: 3 offset_ps: 20000 duration_ps: 10000 } }
+  lines { id: 3 name: "XLA Modules" timestamp_ns: 0
+          events { metadata_id: 2 offset_ps: 32000 duration_ps: 1000 } }
+  lines { id: 4 name: "XLA Ops" timestamp_ns: 0
+          events { metadata_id: 4 offset_ps: 32000 duration_ps: 1000 }
+          events { metadata_id: 5 offset_ps: 50000 duration_ps: 10000 } }
+  event_metadata { key: 1 value { id: 1 name: "jit_slab(11)" } }
+  event_metadata { key: 2 value { id: 2 name: "jit_broadcast_in_dim(12)" } }
+  event_metadata { key: 3 value { id: 3 name: "%fusion.2 = f32[8]{0} fusion(%copy.7)" } }
+  event_metadata { key: 4 value { id: 4 name: "%broadcast.1 = f32[8]{0} broadcast(%c)" } }
+  event_metadata { key: 5 value { id: 5 name: "%fusion.12 = f32[8]{0} fusion(%gte.9)" } }
+}
+planes {
+  id: 2 name: "/host:CPU"
+  lines { id: 1 name: "python" timestamp_ns: 0
+          events { metadata_id: 1 offset_ps: 0 duration_ps: 100000 } }
+  event_metadata { key: 1 value { id: 1 name: "bench.window" } }
+}
+"""
+
+
+def test_load_reads_every_line_of_a_name_on_a_device(tmp_path):
+    from jax.profiler import ProfileData
+    from chipbench import trace
+    path = tmp_path / "split.xplane.pb"
+    path.write_bytes(ProfileData.text_proto_to_serialized_xspace(
+        SPLIT_PLANES))
+    planes = trace.load(str(path))
+    assert [n for n, _, _ in planes["/device:TPU:0"]["XLA Ops"]] == [
+        "%fusion.2 = f32[8]{0} fusion(%copy.7)",
+        "%broadcast.1 = f32[8]{0} broadcast(%c)",
+        "%fusion.12 = f32[8]{0} fusion(%gte.9)"]
+    assert trace.reduce(planes)["devices"][0]["busy_ns"] == 10 + 1 + 10
+    got = scopes.load(str(path))
+    assert list(got.ops) == ["/device:TPU:0/XLA Ops"]
+    # an op takes the latest module to start that holds it, and the slab
+    # again once the short program has ended
+    assert [(o.module, o.op) for o in got.ops["/device:TPU:0/XLA Ops"]] == [
+        ("jit_slab", "fusion.2"), ("jit_broadcast_in_dim", "broadcast.1"),
+        ("jit_slab", "fusion.12")]
+    assert [m for m, _, _ in got.modules["/device:TPU:0"]] == [
+        "jit_slab", "jit_broadcast_in_dim"]
+    red = scopes.reduce(got, scopes.scope_map([SLAB, SLABFIN]))
+    d = red["devices"]["/device:TPU:0"]
+    assert d["scopes_ns"]["uplink.reduce"] == 10.0
+    assert d["scopes_ns"]["uplink.encode"] == 10.0
+    assert d["scopes_ns"][scopes.UNSCOPED] == 1.0

@@ -118,10 +118,32 @@ def test_roofline_reader_needs_every_call_and_stays_under_100():
     c = {"chips": 1, "leaf_sizes": sizes, "traced_rounds": 3}
     need = sum(m + 4 * m + 4 for m in sizes) * 2 * 3
     least_ns = need / peaks["hbm_bytes_per_s"] * 1e9
-    t = {"devices": {0: {"kernels": {
-        "int8_decompress_reduce": (4 * least_ns, 12)}}}}
-    ctx = {"trace": t, "geometry": g, "counters": c, "peaks": peaks}
+    red = {"devices": {"/device:TPU:0": {"pallas_ns": {
+        "uplink.reduce": [4 * least_ns, 12]}}}}
+    ctx = {"layers": red, "geometry": g, "counters": c, "peaks": peaks}
     assert mod.read(ctx) == pytest.approx(25.0)
-    t["devices"][0]["kernels"]["int8_decompress_reduce"] = (least_ns, 11)
+    red["devices"]["/device:TPU:0"]["pallas_ns"]["uplink.reduce"] = [
+        least_ns, 11]
     assert mod.read(ctx) is None
     assert mod.read(dict(ctx, geometry=dict(g, transport="none"))) is None
+    assert mod.read(dict(ctx, layers=None)) is None
+
+
+def test_roofline_reader_on_a_mesh_counts_each_chip_s_own_clients():
+    mod = catalog.load_module(ROOT / "metrics" / "int8_reduce_roofline.py",
+                              "m4")
+    peaks = catalog.load_peaks("TPU v5 lite")
+    sizes = [1000, 24000]
+    g = {"transport": "int8", "cohort_chunk": 4, "clients_per_round": 4}
+    c = {"chips": 4, "leaf_sizes": sizes, "traced_rounds": 3}
+    # one slab a round, one client on each chip
+    least_ns = sum(m + 4 * m + 4 for m in sizes) * 3 \
+        / peaks["hbm_bytes_per_s"] * 1e9
+    red = {"devices": {f"/device:TPU:{d}": {"pallas_ns": {
+        "uplink.reduce": [2 * least_ns, 6]}} for d in range(4)}}
+    ctx = {"layers": red, "geometry": g, "counters": c, "peaks": peaks}
+    assert mod.read(ctx) == pytest.approx(50.0)
+    # a chip whose plane lacks a call reads nothing for the whole cell
+    red["devices"]["/device:TPU:0"]["pallas_ns"]["uplink.reduce"] = [
+        least_ns, 5]
+    assert mod.read(ctx) is None
